@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import spmm
 from repro.exec import execute_sddmm
 from .common import emit, load_dataset, time_fn
@@ -51,6 +52,7 @@ def run(max_dim: int = 1024) -> None:
 
 
 def main(argv=None) -> None:
+    enable_compile_cache()
     p = argparse.ArgumentParser(description=__doc__)
     p.add_argument("--max-dim", type=int, default=1024)
     args = p.parse_args(argv)

@@ -59,6 +59,7 @@ def check_roofline(attr: dict) -> None:
         _fail("roofline attribution has no rows — profiler saw no "
               "telemetry-enabled dispatches")
     attributed = 0.0
+    priced_measured = 0.0
     for row in rows:
         missing = ROW_KEYS - set(row)
         if missing:
@@ -66,28 +67,39 @@ def check_roofline(attr: dict) -> None:
         if set(row["paths"]) != {"matrix", "fringe"}:
             _fail(f"row {row['sig']!r} paths are {set(row['paths'])}, "
                   "want {'matrix', 'fringe'}")
+        priced = bool(row["peaks"])
         for p, acc in row["paths"].items():
             if PATH_KEYS - set(acc):
                 _fail(f"row {row['sig']!r} path {p!r} missing "
                       f"{PATH_KEYS - set(acc)}")
+            if not priced:
+                # a device kind without peaks gets no share at all
+                if acc["share"] is not None or row["utilization"] is not None:
+                    _fail(f"row {row['sig']!r} has no device peaks but "
+                          "reports a roofline share")
+                continue
             attributed += acc["attributed_us"]
         if row["calls"] < 1 or row["measured_us"] <= 0:
             _fail(f"row {row['sig']!r} has no measured work")
+        if priced:
+            priced_measured += row["measured_us"]
     for p in ("matrix_path", "fringe_path"):
         if TOTAL_KEYS - set(attr[p]):
             _fail(f"{p} totals missing {TOTAL_KEYS - set(attr[p])}")
     total = attr["measured_us_total"]
     if total <= 0:
         _fail("measured_us_total is zero")
-    if abs(attributed - total) > 1e-6 * max(total, 1.0):
+    if abs(attributed - priced_measured) > 1e-6 * max(priced_measured, 1.0):
         _fail(f"attributed time {attributed:.3f}us does not add up to "
-              f"measured total {total:.3f}us")
+              f"measured priced total {priced_measured:.3f}us")
 
 
 def check_prometheus(text: str, attr: dict) -> None:
     parsed = parse_prometheus_text(text)
-    for name in ("repro_roofline_calls", "repro_roofline_measured_us",
-                 "repro_roofline_bound_us"):
+    required = ["repro_roofline_calls", "repro_roofline_measured_us"]
+    if any(row["peaks"] for row in attr["rows"]):
+        required.append("repro_roofline_bound_us")  # priced rows only
+    for name in required:
         if name not in parsed:
             _fail(f"Prometheus export missing {name}")
     for row in attr["rows"]:
@@ -100,6 +112,11 @@ def check_prometheus(text: str, attr: dict) -> None:
     for name in REQUIRED_METRICS:
         if not any(n == name or n.startswith(name + "_") for n in parsed):
             _fail(f"Prometheus export missing registry metric {name}")
+
+
+def _pct(x) -> str:
+    return "not priced (device kind has no peaks)" if x is None \
+        else f"{100.0 * x:.1f}%"
 
 
 def main(argv=None) -> int:
@@ -122,7 +139,7 @@ def main(argv=None) -> int:
     print(f"OK: telemetry snapshot valid — {len(rows)} roofline row(s), "
           f"{len(snap['traces'])} trace(s), "
           f"{len(snap['metrics'])} registry metric(s), "
-          f"utilization {100.0 * snap['roofline']['utilization']:.1f}%")
+          f"utilization {_pct(snap['roofline']['utilization'])}")
     return 0
 
 

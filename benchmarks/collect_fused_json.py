@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import spmm
 from .common import BENCH_DATASETS, geomean, load_dataset, time_fn
 
@@ -48,6 +49,7 @@ def _calibration_us(rng: np.random.RandomState) -> float:
 
 
 def main(argv=None) -> None:
+    enable_compile_cache()
     p = argparse.ArgumentParser(description=__doc__)
     p.add_argument("--datasets", nargs="*", default=list(BENCH_DATASETS))
     p.add_argument("--max-dim", type=int, default=SEED_DIM)

@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import spmm
 from repro.exec import execute_sddmm
 from .common import geomean, load_dataset, time_fn
@@ -31,6 +32,7 @@ def _calibration_us(rng: np.random.RandomState) -> float:
 
 
 def main(argv=None) -> None:
+    enable_compile_cache()
     p = argparse.ArgumentParser(description=__doc__)
     p.add_argument("--datasets", nargs="*", default=["cora", "F1", "reddit"])
     p.add_argument("--max-dim", type=int, default=512)

@@ -6,8 +6,8 @@ dataset plus the ``calib_us`` dense-matmul machine anchor), measured through
 ``benchmarks/check_regression.py`` gates it unchanged against
 ``benchmarks/baseline_sharded_ci.json``.
 
-This module forces the host device count itself (before jax initializes),
-so it runs identically on a laptop and in CI:
+``main`` forces the host device count itself (before jax creates its
+backend), so it runs identically on a laptop and in CI:
 
     PYTHONPATH=src python -m benchmarks.collect_sharded_json \
         --datasets cora F1 reddit --max-dim 512 --out sharded_fresh.json
@@ -16,19 +16,18 @@ import argparse
 import json
 import os
 
-from repro.hostdevices import force_host_device_count  # jax-free
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from repro.compile_cache import enable_compile_cache
+from repro.core import spmm
+from repro.hostdevices import force_host_device_count
+from repro.launch.mesh import make_spmm_mesh
+
+from .common import geomean, load_dataset, time_fn
 
 N_FORCED_DEVICES = 8
-force_host_device_count(os.environ, N_FORCED_DEVICES)
-
-import jax  # noqa: E402
-import jax.numpy as jnp  # noqa: E402
-import numpy as np  # noqa: E402
-
-from repro.core import spmm  # noqa: E402
-from repro.launch.mesh import make_spmm_mesh  # noqa: E402
-
-from .common import geomean, load_dataset, time_fn  # noqa: E402
 
 
 def _calibration_us(rng: np.random.RandomState) -> float:
@@ -40,6 +39,8 @@ def _calibration_us(rng: np.random.RandomState) -> float:
 
 
 def main(argv=None) -> None:
+    force_host_device_count(os.environ, N_FORCED_DEVICES)
+    enable_compile_cache()
     p = argparse.ArgumentParser(description=__doc__)
     p.add_argument("--datasets", nargs="*", default=["cora", "F1", "reddit"])
     p.add_argument("--max-dim", type=int, default=512)

@@ -17,6 +17,7 @@ import argparse
 import json
 import sys
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import spmm, tuner
 from repro.dynamic.tuning import install_registry_store
 
@@ -28,6 +29,7 @@ DEFAULT_DATASETS = ["cora", "F1", "reddit"]
 
 
 def main(argv=None) -> int:
+    enable_compile_cache()
     p = argparse.ArgumentParser(description=__doc__)
     p.add_argument("--registry", required=True,
                    help="PlanRegistry root to persist the tuning table in")

@@ -6,6 +6,8 @@ Prints ``name,us_per_call,derived`` CSV rows.
 """
 import sys
 
+from repro.compile_cache import enable_compile_cache
+
 SUITES = [
     "bench_overall",        # Fig. 15
     "bench_coordination",   # Fig. 16
@@ -25,6 +27,7 @@ SUITES = [
 
 
 def main() -> None:
+    enable_compile_cache()
     only = set(sys.argv[1:])
     print("name,us_per_call,derived")
     for suite in SUITES:

@@ -37,11 +37,45 @@ from typing import Callable, Optional
 
 import numpy as np
 
-# TPU v5e-class constants (match the roofline brief)
-PEAK_FLOPS_BF16 = 197e12  # per chip
-HBM_BW = 819e9  # bytes/s
-ICI_BW = 50e9  # bytes/s/link
-VMEM_BYTES = 16 * 1024 * 1024
+from ..kernels.gather_spmm import STEP as FRINGE_STEP
+
+@dataclasses.dataclass(frozen=True)
+class DevicePeaks:
+    """Published per-chip ceilings of one accelerator kind."""
+
+    flops_per_s: float       # dense bf16 matmul
+    bytes_per_s: float       # HBM bandwidth
+    ici_bytes_per_s: float   # chip-to-chip interconnect, per link
+    source: str
+
+
+#: Roofline peaks keyed by ``jax.Device.device_kind``.  A kind that is not
+#: listed has no peaks: no roofline share is computed for it.
+DEVICE_PEAKS = {
+    "TPU v5 lite": DevicePeaks(
+        flops_per_s=197e12,
+        bytes_per_s=819e9,
+        # 1,600 Gbit/s of interchip interconnect per chip, over 4 links
+        ici_bytes_per_s=50e9,
+        source="Google Cloud documentation, 'TPU v5e' "
+               "(cloud.google.com/tpu/docs/v5e)",
+    ),
+}
+
+
+def device_peaks(device_kind: str) -> Optional[DevicePeaks]:
+    """Peaks of ``device_kind``, or None when the table does not list it."""
+    return DEVICE_PEAKS.get(device_kind)
+
+
+# the analytic model prices the target chip, TPU v5e
+_TARGET = DEVICE_PEAKS["TPU v5 lite"]
+PEAK_FLOPS_BF16 = _TARGET.flops_per_s
+HBM_BW = _TARGET.bytes_per_s
+VMEM_BYTES = 16 * 1024 * 1024  # default scoped VMEM limit of one kernel
+# scalar memory: the v5e compiler refuses a kernel whose SMEM operands
+# exceed 1 MiB ("Allocation ... would exceed memory (size=1048576)")
+SMEM_BYTES = 1024 * 1024
 MXU_DIM = 128  # systolic array edge; min efficient tile
 VPU_LANES = 128
 SUBLANES = 8
@@ -153,9 +187,10 @@ class EngineCostModel:
 
     def select_fringe_tier(
         self, k: int, num_rows: int, bn: int,
-        vmem_budget: Optional[int] = None,
+        vmem_budget: Optional[int] = None, nnz: int = 0,
     ) -> tuple:
-        return select_fringe_tier(k, num_rows, bn, vmem_budget=vmem_budget)
+        return select_fringe_tier(k, num_rows, bn, vmem_budget=vmem_budget,
+                                  nnz=nnz)
 
     def select_sddmm_tier(
         self, d: int, n_src_rows: int, n_dst_rows: int,
@@ -277,6 +312,53 @@ def _pad_rows(num_rows: int) -> int:
 def fringe_resident_bytes(k: int, num_rows: int, bn: int) -> int:
     """Tier-(a) working set: full (K, bn) B panel + packed fp32 out block."""
     return (k + _pad_rows(num_rows)) * bn * 4
+
+
+# --- scalar-memory (SMEM) claims ---------------------------------------------
+# The gather kernels stream their nonzero ids through SMEM one grid step at
+# a time (kernels.gather_spmm.STEP entries per stream, double-buffered), so
+# their SMEM claim is fixed — except the K-sharded tier's chunk -> k-block
+# map, which an index map reads and is therefore scalar-prefetched whole.
+# The matrix-path kernels prefetch their (window, k-block) step metadata
+# whole too, 8 bytes per tile step: compiling dense_tile_spmm for a v5e
+# passes at 130,000 steps and is refused at 131,000 (Mosaic keeps ~2 KiB of
+# its own), so 32 KiB stay in reserve.
+SMEM_BUDGET = SMEM_BYTES - 32 * 1024
+
+
+def stream_smem_bytes(n_streams: int) -> int:
+    """SMEM of ``n_streams`` double-buffered STEP-entry 32-bit blocks."""
+    return n_streams * 2 * FRINGE_STEP * 4
+
+
+def fringe_smem_bytes(tier: str, k: int, bk: int, nnz: int) -> int:
+    """SMEM claim of a gather-SpMM tier over a fringe of ``nnz`` entries."""
+    if tier == "resident":
+        return stream_smem_bytes(3)
+    if tier == "ksharded":
+        n_kb = -(-int(k) // int(bk))
+        # each nonempty k-block bucket is padded to a STEP multiple
+        num_chunks = -(-int(nnz) // FRINGE_STEP) + min(n_kb, max(int(nnz), 1))
+        return stream_smem_bytes(3) + 4 * num_chunks
+    return 0
+
+
+def assert_smem_claim(claim_bytes: int, what: str) -> None:
+    """Backstop against a kernel whose SMEM operands cannot fit the chip."""
+    if claim_bytes > SMEM_BUDGET:
+        raise ValueError(
+            f"{what} needs ~{claim_bytes / 2**10:.0f} KiB of SMEM "
+            f"(> {SMEM_BUDGET / 2**10:.0f} KiB budget of the "
+            f"{SMEM_BYTES / 2**10:.0f} KiB scalar memory); use the XLA tier "
+            "for this shape"
+        )
+
+
+def assert_step_metadata_smem(num_steps: int, kernel: str) -> None:
+    """Backstop for a matrix-path kernel, which scalar-prefetches its
+    step_window + step_col whole (8 bytes per tile step)."""
+    assert_smem_claim(8 * int(num_steps),
+                      f"{kernel} step metadata (T={num_steps})")
 
 
 def fringe_ksharded_bytes(bk: int, num_rows: int, bn: int) -> int:
@@ -458,9 +540,14 @@ def ksharded_bk_cap(k: int, num_rows: int, bn: int, budget: int) -> int:
 
 
 def select_fringe_tier(
-    k: int, num_rows: int, bn: int, vmem_budget: Optional[int] = None
+    k: int, num_rows: int, bn: int, vmem_budget: Optional[int] = None,
+    nnz: int = 0,
 ) -> tuple:
     """Pick the vector-path kernel tier for a fringe of this shape.
+
+    ``nnz`` (the fringe's nonzero count) prices the SMEM the K-sharded
+    tier's prefetched chunk map claims; a tier whose VMEM *or* SMEM claim
+    does not fit is never picked.
 
     Returns ``(tier, bk)``:
       - ``("resident", 0)``  — single-panel kernel; whole (K, bn) B panel
@@ -476,7 +563,7 @@ def select_fringe_tier(
     if fringe_resident_bytes(k, num_rows, bn) <= budget:
         return "resident", 0
     bk = ksharded_bk_cap(k, num_rows, bn, budget)
-    if bk:
+    if bk and fringe_smem_bytes("ksharded", k, bk, nnz) <= SMEM_BUDGET:
         return "ksharded", bk
     return "xla", 0
 
@@ -506,11 +593,11 @@ def assert_vmem_claim(claim_bytes: int, what: str) -> None:
 # binary: resident pallas gather, or the XLA reference gather.
 
 
-def sddmm_resident_bytes(d: int, n_src_rows: int, n_dst_rows: int,
-                         chunk: int = 64) -> int:
-    """SDDMM gather working set: X panel + Y^T panel + one output chunk."""
+def sddmm_resident_bytes(d: int, n_src_rows: int, n_dst_rows: int) -> int:
+    """SDDMM gather working set: X panel + Y^T panel + one output block
+    (STEP dots)."""
     return (_pad_rows(n_src_rows) + _pad_rows(n_dst_rows)) * d * 4 + \
-        _pad_rows(chunk) * VPU_LANES * 4
+        FRINGE_STEP * 4
 
 
 def select_sddmm_tier(
@@ -519,6 +606,8 @@ def select_sddmm_tier(
 ) -> str:
     """Pick the SDDMM fringe-gather tier: ``"resident"`` or ``"xla"``."""
     budget = FRINGE_VMEM_BUDGET if vmem_budget is None else int(vmem_budget)
-    if sddmm_resident_bytes(d, n_src_rows, n_dst_rows) <= budget:
+    # SMEM holds only the two streamed id blocks, whatever the nnz
+    if (sddmm_resident_bytes(d, n_src_rows, n_dst_rows) <= budget
+            and stream_smem_bytes(2) <= SMEM_BUDGET):
         return "resident"
     return "xla"

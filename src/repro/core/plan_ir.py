@@ -583,12 +583,13 @@ def gather_rows(packed: jax.Array, src: jax.Array) -> jax.Array:
 
 def bucket_fringe_kblocks(
     pr: np.ndarray, pc: np.ndarray, pv: np.ndarray,
-    k_pad: int, fringe_bk: int, chunk_eff: int,
+    k_pad: int, fringe_bk: int, chunk: int = ops.FRINGE_STEP,
 ) -> Tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
     """Relayout packed fringe COO for the K-sharded streaming kernel.
 
-    Nonzeros sorted by (k-block, row, col), per-bucket padded to a chunk
-    multiple with zero-value entries, columns made k-block-local; empty
+    Nonzeros sorted by (k-block, row, col), per-bucket padded to a
+    ``chunk`` multiple with zero-value entries (the kernel's grid step, so
+    each chunk is one step), columns made k-block-local; empty
     k-blocks get no chunks (their B slices are never fetched).  Shared by
     ``prepare`` and ``prepare_sharded`` (which re-buckets every shard with
     one mesh-wide bk so all shards run the same kernel).  The trailing
@@ -601,7 +602,7 @@ def bucket_fringe_kblocks(
     order_kb = np.argsort(kb, kind="stable")  # keeps (row, col) per kb
     kbs = kb[order_kb]
     counts = np.bincount(kbs, minlength=nkb_f)
-    padded = ((counts + chunk_eff - 1) // chunk_eff) * chunk_eff
+    padded = ((counts + chunk - 1) // chunk) * chunk
     src_start = np.cumsum(counts) - counts
     dst_start = np.cumsum(padded) - padded
     dest = dst_start[kbs] + np.arange(kbs.size) - src_start[kbs]
@@ -613,7 +614,7 @@ def bucket_fringe_kblocks(
     kb_vals = np.zeros(total_kb, pv.dtype)
     kb_vals[dest] = pv[order_kb]
     kb_chunk = np.repeat(
-        np.arange(nkb_f, dtype=np.int32), padded // chunk_eff
+        np.arange(nkb_f, dtype=np.int32), padded // chunk
     )
     pos_of_packed = np.empty(kbs.size, np.int64)
     pos_of_packed[order_kb] = dest
@@ -878,18 +879,21 @@ def build_delta_fringe(
     # plan fringe; the packed-row bound is the capacity (static per sig)
     k_pad = ((k + config.bk - 1) // config.bk) * config.bk
     tier, dbk = select_fringe_tier(
-        k_pad, cap, config.bn, vmem_budget=config.fringe_vmem_budget
+        k_pad, cap, config.bn, vmem_budget=config.fringe_vmem_budget,
+        nnz=cap,
     )
-    chunk_eff = ops.effective_chunk(config.fringe_chunk)
     if tier == "ksharded" and config.impl != "xla":
+        step = ops.FRINGE_STEP
         kbc, kbr, kbcol, kbv, _pos = bucket_fringe_kblocks(
-            pr, pc, pv, k_pad, dbk, chunk_eff
+            pr, pc, pv, k_pad, dbk
         )
         # deterministic shapes per capacity: each nonempty bucket wastes
-        # < chunk slots, so cap * chunk bounds the bucketed stream; pad
-        # chunks target k-block 0 with zero values (accumulate-inert)
-        kb_cap = cap * chunk_eff
-        kbc = _pad_clip(kbc, kb_cap // chunk_eff)
+        # < step slots and at most min(cap, k-blocks) buckets are nonempty,
+        # which bounds the bucketed stream; pad chunks target k-block 0
+        # with zero values (accumulate-inert)
+        n_kb = -(-k_pad // dbk)
+        kb_cap = (-(-cap // step) + min(cap, n_kb)) * step
+        kbc = _pad_clip(kbc, kb_cap // step)
         kbr = _pad_clip(kbr, kb_cap)
         kbcol = _pad_clip(kbcol, kb_cap)
         kbv = _pad_clip(kbv, kb_cap)

@@ -33,11 +33,13 @@ import dataclasses
 import time
 from typing import Any, List, Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding
 
+from ..distributed.sharding import leading_axis_spec, replicated_spec
 from ..errors import PlanBuildError
-from ..kernels import ops
 from . import formats, partition, plan_ir, reorder, reuse
 from .coordinator import (
     balance_row_window_list, list_imbalance, window_costs_from_coo,
@@ -361,15 +363,13 @@ def prepare(
     k_pad = ((k + config.bk - 1) // config.bk) * config.bk
     fringe_tier, fringe_bk = cm.select_fringe_tier(
         k_pad, int(fringe_row_ids.shape[0]), config.bn,
-        vmem_budget=config.fringe_vmem_budget,
+        vmem_budget=config.fringe_vmem_budget, nnz=int(f_rows.size),
     )
     # the bucketed stream is only consumed by the pallas kernels; xla-impl
     # plans skip the bucketing sort/scatter passes (tier is still recorded)
     if fringe_tier == "ksharded" and f_rows.size and config.impl != "xla":
-        chunk_eff = ops.effective_chunk(config.fringe_chunk)
         kb_chunk, kb_rows, kb_cols, kb_vals, kb_pos_of_packed = (
-            plan_ir.bucket_fringe_kblocks(pr, pc, pv, k_pad, fringe_bk,
-                                          chunk_eff)
+            plan_ir.bucket_fringe_kblocks(pr, pc, pv, k_pad, fringe_bk)
         )
     else:
         kb_chunk = np.zeros(1, np.int32)
@@ -458,6 +458,23 @@ def prepare(
 # one gather — no psum, no scatter-add.
 
 
+def _place_on_mesh(arrays, mesh, axis_name: str, stacked: bool) -> Tuple:
+    """Put plan arrays where the sharded executor reads them.
+
+    Stacked per-shard leaves split along their leading shard axis (each
+    device holds only its own shard); everything else is replicated.  Left
+    on the default device, every dispatch would first copy the whole plan
+    out of device 0.
+    """
+    def spec(x):
+        if stacked:
+            return leading_axis_spec(x.ndim, axis_name)
+        return replicated_spec(x.ndim)
+
+    return tuple(jax.device_put(x, NamedSharding(mesh, spec(x)))
+                 for x in arrays)
+
+
 def prepare_sharded(
     rows: np.ndarray,
     cols: np.ndarray,
@@ -533,7 +550,9 @@ def prepare_sharded(
             key_sorted=um.key_sorted, key_order=um.key_order,
         )
         return ShardedPlan(
-            leaves=plan_ir.plan_leaves(plan), sig=plan.signature(), mesh=mesh,
+            leaves=_place_on_mesh(plan_ir.plan_leaves(plan), mesh, axis_name,
+                                  stacked=False),
+            sig=plan.signature(), mesh=mesh,
             axis_name=axis_name, shard_axis="rhs", n_shards=n_shards,
             assemble=None, shape=tuple(shape), config=config,
             stats=base_stats + (("nnz", int(rows.shape[0])),),
@@ -604,16 +623,15 @@ def prepare_sharded(
     has_core = any(p.has_core for p in plans)
     has_fringe = any(p.has_fringe for p in plans)
     u_tier, u_bk = cm.select_fringe_tier(
-        k_pad, nfr_max, cfg.bn, vmem_budget=cfg.fringe_vmem_budget
+        k_pad, nfr_max, cfg.bn, vmem_budget=cfg.fringe_vmem_budget,
+        nnz=nnzf_max,
     )
-    chunk_eff = ops.effective_chunk(cfg.fringe_chunk)
-
     kb_streams = []
     for p in plans:
         if u_tier == "ksharded" and p.has_fringe and cfg.impl != "xla":
             kb_streams.append(plan_ir.bucket_fringe_kblocks(
                 np.asarray(p.fringe_rows), np.asarray(p.fringe_cols),
-                np.asarray(p.fringe_vals), k_pad, u_bk, chunk_eff,
+                np.asarray(p.fringe_vals), k_pad, u_bk,
             ))
         else:
             kb_streams.append((
@@ -626,9 +644,9 @@ def prepare_sharded(
     # the kernel window count grows by one: padded tile-stream steps target
     # the dedicated window nw_max, never a real slot (see stack_shard_leaves)
     nw_kernel = nw_max + 1
-    leaves = plan_ir.stack_shard_leaves(
+    leaves = _place_on_mesh(plan_ir.stack_shard_leaves(
         plans, kb_streams, t_max, nw_max, nnzf_max, nch_max, nnzkb_max
-    )
+    ), mesh, axis_name, stacked=True)
 
     sig = (
         PLAN_FORMAT_VERSION,
@@ -688,6 +706,8 @@ def prepare_sharded(
     return ShardedPlan(
         leaves=leaves, sig=sig, mesh=mesh, axis_name=axis_name,
         shard_axis="rows", n_shards=n_shards,
-        assemble=jnp.asarray(assemble), shape=tuple(shape), config=config,
+        assemble=_place_on_mesh((assemble,), mesh, axis_name,
+                                stacked=False)[0],
+        shape=tuple(shape), config=config,
         stats=stats, update_maps=smaps, rows_per_shard=m_loc_max,
     )

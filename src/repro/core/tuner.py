@@ -49,11 +49,13 @@ from .cost_model import (
     FRINGE_VMEM_BUDGET,
     MXU_DIM,
     ROWS_IMBALANCE_THRESHOLD,
+    SMEM_BUDGET,
     SUBLANES,
     VMEM_BYTES,
     EngineCostModel,
     default_cost_model,
     fringe_resident_bytes,
+    fringe_smem_bytes,
     ksharded_bk_cap,
     select_fringe_tier,
 )
@@ -223,7 +225,7 @@ class TunedCostModel(EngineCostModel):
 
     def select_fringe_tier(
         self, k: int, num_rows: int, bn: int,
-        vmem_budget: Optional[int] = None,
+        vmem_budget: Optional[int] = None, nnz: int = 0,
     ) -> tuple:
         budget = (
             FRINGE_VMEM_BUDGET if vmem_budget is None else int(vmem_budget)
@@ -241,8 +243,11 @@ class TunedCostModel(EngineCostModel):
                 cap = ksharded_bk_cap(k, num_rows, bn, budget)
                 if cap:
                     bk = min(bk, cap) if bk >= SUBLANES else cap
-                    return "ksharded", (bk // SUBLANES) * SUBLANES
-        return select_fringe_tier(k, num_rows, bn, vmem_budget=vmem_budget)
+                    bk = (bk // SUBLANES) * SUBLANES
+                    if fringe_smem_bytes(tier, k, bk, nnz) <= SMEM_BUDGET:
+                        return "ksharded", bk
+        return select_fringe_tier(k, num_rows, bn, vmem_budget=vmem_budget,
+                                  nnz=nnz)
 
     def select_sddmm_tier(
         self, d: int, n_src_rows: int, n_dst_rows: int,
@@ -615,7 +620,8 @@ class Tuner:
         """Binary sddmm sweep: resident pallas gather vs XLA reference.
 
         Only meaningful for pallas impls (the xla impl never consults the
-        tier); on CPU the resident candidate runs in interpret mode, so a
+        tier).  The resident candidate runs on the plan's own impl, so a
+        ``pallas_interpret`` plan on CPU times the interpreter and a
         measured "xla" preference there is the measurement working as
         intended.  Demote-only: a resident preference is not recorded (the
         analytic budget check already picks it when it fits).
@@ -633,7 +639,7 @@ class Tuner:
         t_res = self._timed(
             "sddmm:resident",
             lambda: kops.sddmm_gather(
-                srows, scols, x, yt, impl="pallas_interpret", tier="resident"
+                srows, scols, x, yt, impl=config.impl, tier="resident"
             ),
             rec,
         )

@@ -44,33 +44,15 @@ class AxisRules:
 
 
 # ---------------------------------------------------------------------------
-# shard_map compat + PartitionSpec helpers (used by the sharded SpMM
+# shard_map + PartitionSpec helpers (used by the sharded SpMM
 # executor, core/spmm.py: per-shard plan leaves ride a leading mesh axis,
 # RHS-column sharding rides a trailing one)
 # ---------------------------------------------------------------------------
 def shard_map(f, mesh, in_specs, out_specs):
-    """Version-compat shard_map: ``jax.shard_map`` on new releases, the
-    experimental module on 0.4.x (where the public alias does not exist).
-
-    Replication checking is disabled under whichever keyword this jax
-    spells it (``check_rep`` on 0.4.x, ``check_vma`` later): the sharded
-    SpMM bodies wrap pallas_call, which has no replication rule.
-    """
-    import inspect
-
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-    kwargs = {"mesh": mesh, "in_specs": in_specs, "out_specs": out_specs}
-    try:
-        params = inspect.signature(sm).parameters
-    except (TypeError, ValueError):  # pragma: no cover - exotic wrappers
-        params = {}
-    for check_kw in ("check_rep", "check_vma"):
-        if check_kw in params:
-            kwargs[check_kw] = False
-            break
-    return sm(f, **kwargs)
+    """``jax.shard_map`` with replication checking off: the sharded SpMM
+    bodies wrap pallas_call, which has no replication rule."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def axis_spec(rank: int, pos: int, axis: Optional[str]) -> P:

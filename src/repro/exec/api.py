@@ -10,7 +10,9 @@ working unchanged.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
+import warnings
 import zlib
 from typing import Tuple
 
@@ -19,7 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core import plan_ir, tuner
-from ..core.cost_model import HBM_BW, PEAK_FLOPS_BF16, matrix_payload_bytes
+from ..core.cost_model import device_peaks, matrix_payload_bytes
 from ..core.plan_ir import (
     NeutronPlan, ShardedPlan, SpmmConfig, build_sddmm_maps, gather_rows,
     permute_pad_b, plan_leaves, sddmm_body_leaves, validate_rhs,
@@ -35,10 +37,19 @@ from .cache import (  # noqa: F401  (re-exported test hooks)
 from .health import HEALTH
 from .pipeline import build_delta_only_executor, build_executor
 
-# roofline ceilings the telemetry profiler reports modeled work against;
-# the analytic cost model's device constants (obs itself never imports the
-# cost model, so they ride on every record)
-_PEAKS = {"flops_per_s": PEAK_FLOPS_BF16, "bytes_per_s": HBM_BW}
+@functools.cache
+def _device_peaks() -> dict:
+    """Roofline ceilings the telemetry profiler reports modeled work against.
+
+    Looked up by the dispatch device's kind in ``cost_model.DEVICE_PEAKS``
+    (obs itself never imports the cost model, so they ride on every
+    record).  An unlisted kind — the CPU among them — gets no peaks, and
+    the roofline report then computes no share for it.
+    """
+    peaks = device_peaks(jax.devices()[0].device_kind)
+    if peaks is None:
+        return {}
+    return {"flops_per_s": peaks.flops_per_s, "bytes_per_s": peaks.bytes_per_s}
 
 
 def _apply_cache_capacity(config: SpmmConfig) -> None:
@@ -104,7 +115,7 @@ def _maybe_profiled(fn, args, *, kind, sig, tier, prof):
     PROFILER.record(
         op=prof["op"], tier=str(tier), sig_key=_sig_key(sig), kind=kind,
         measured_us=measured_us, traced=traced, batch=prof.get("batch"),
-        terms=prof["terms"], peaks=_PEAKS, attrs=prof.get("attrs"),
+        terms=prof["terms"], peaks=_device_peaks(), attrs=prof.get("attrs"),
     )
     return out
 
@@ -146,12 +157,19 @@ def _guarded_call(sig, config: SpmmConfig, make_fn, args, kind: str, key_of,
             HEALTH.record_success(sig)
             return out
         except Exception as err:  # noqa: BLE001 — any accel failure degrades
-            HEALTH.record_failure(sig, err)
+            first = HEALTH.record_failure(sig, err)
             if not config.degrade_to_xla:
                 raise KernelLoweringError(
                     f"accelerated executor failed for impl={impl!r} and "
                     f"degrade_to_xla is disabled: {err}"
                 ) from err
+            if first:
+                warnings.warn(
+                    f"{kind} executor for impl={impl!r} failed and is served "
+                    f"by the XLA tier (signature {_sig_key(sig)}): "
+                    f"{type(err).__name__}: {err}",
+                    RuntimeWarning, stacklevel=3,
+                )
     fsig = plan_ir.xla_fallback_sig(sig)
     HEALTH.record_fallback(sig)
     try:

@@ -112,7 +112,8 @@ class HealthTable:
                 return True
             return rec.calls_seen >= rec.next_retry_call
 
-    def record_failure(self, sig: Tuple, err: BaseException) -> None:
+    def record_failure(self, sig: Tuple, err: BaseException) -> bool:
+        """Record an accel failure; True on the signature's first one."""
         with self._lock:
             rec = self._rec(sig)
             rec.failures += 1
@@ -126,6 +127,7 @@ class HealthTable:
             else:
                 rec.next_retry_call = rec.calls_seen + (
                     self.backoff_base ** rec.consecutive_failures)
+            return rec.failures == 1
 
     def record_success(self, sig: Tuple) -> None:
         with self._lock:

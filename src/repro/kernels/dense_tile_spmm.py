@@ -32,8 +32,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import tpu_compiler_params
-
 
 def _kernel(
     step_window_ref,  # scalar prefetch: (T,) int32
@@ -79,6 +77,11 @@ def dense_tile_spmm(
     k, n = b.shape
     assert k % bk == 0 and n % bn == 0, (k, bk, n, bn)
 
+    if not interpret:
+        from ..core.cost_model import assert_step_metadata_smem
+
+        assert_step_metadata_smem(t_steps, "dense_tile_spmm")
+
     grid = (n // bn, t_steps)
     out = pl.pallas_call(
         _kernel,
@@ -92,9 +95,10 @@ def dense_tile_spmm(
             out_specs=pl.BlockSpec((bm, bn), lambda j, t, w, c: (w[t], j)),
         ),
         out_shape=jax.ShapeDtypeStruct((num_windows * bm, n), jnp.float32),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="dense_tile_spmm",
     )(step_window, step_col, flat_values, b)
     return out

@@ -8,7 +8,8 @@ sharing one chunk-accumulate body, chosen by the VMEM dispatch tier
 
 ``gather_spmm`` (tier "resident")
 
-  grid = (N/bn, ceil(nnz/G))     G = ``chunk`` nonzeros per grid step
+  grid = (N/bn, ceil(nnz/STEP))  STEP nonzeros per grid step
+  rows/cols/vals : (STEP,) SMEM blocks, streamed per step
   B        : B[:, j*bn : ]           (K, bn)        resident across the whole
                                      chunk loop for one n-block (loaded once)
   out      : out[:, j*bn : ]         (num_rows, bn) resident fp32 accumulator,
@@ -18,7 +19,8 @@ sharing one chunk-accumulate body, chosen by the VMEM dispatch tier
 tiled so arbitrarily large K streams through VMEM (Acc-SpMM/FlashSparse
 style k-dimension tiling under the tile-based execution model):
 
-  grid = (N/bn, num_chunks)      chunk c owns G nonzeros of ONE k-block
+  grid = (N/bn, num_chunks)      chunk c owns STEP nonzeros of ONE k-block
+  rows/cols/vals : (STEP,) SMEM blocks, streamed per step
   B        : B[kb[c]*bk : , j*bn : ]  (bk, bn)      streamed per chunk step
                                      (double-buffered by the grid pipeline;
                                      consecutive chunks of one k-block elide
@@ -27,11 +29,12 @@ style k-dimension tiling under the tile-based execution model):
 
 The caller buckets nonzeros by k-block at plan-build time (column ids become
 k-block-local, each bucket padded to a chunk multiple with zero-value
-entries) and prefetches ``chunk_kb`` mapping chunk -> k-block; empty
+entries) and scalar-prefetches ``chunk_kb`` mapping chunk -> k-block (the
+only whole-stream SMEM operand: the B index map reads it); empty
 k-blocks get no chunks at all, so fully inactive B slices are never fetched.
 
-Each grid step walks its G nonzeros with an unrolled, *segment-boundary-
-aware* accumulate: contributions of a run of equal row ids are summed in a
+Each grid step walks its STEP nonzeros in sub-chunks of G = ``chunk``
+(a power of two), each an unrolled, *segment-boundary-aware* accumulate: contributions of a run of equal row ids are summed in a
 register accumulator and flushed to the VMEM output row only when the row id
 changes (the COO is row-sorted within a bucket, so runs are contiguous).
 Partial sums of a row split across k-blocks merge in the resident output
@@ -58,22 +61,43 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import tpu_compiler_params
+# Nonzeros per grid step: one (8, 128) 32-bit tile of each SMEM-blocked
+# stream.  Mosaic blocks a rank-1 operand only in multiples of 128, and a
+# block of exactly one tile wastes no HBM layout padding.
+STEP = 1024
+
+
+def unroll_factor(chunk: int) -> int:
+    """Largest power of two <= ``chunk`` (capped at 64): the sub-chunk the
+    kernels unroll, which therefore always divides a ``STEP`` block."""
+    return 1 << (max(1, min(int(chunk), 64)).bit_length() - 1)
+
+
+def _stream_spec(block: int, index_map) -> pl.BlockSpec:
+    """One ``block``-nonzero slice of a stream per grid step, in SMEM.
+
+    Streaming the slice (instead of scalar-prefetching the whole stream)
+    keeps the SMEM claim at two blocks per stream whatever the fringe's
+    size; a whole prefetched stream overflows the 1 MiB SMEM near 80k
+    nonzeros.
+    """
+    return pl.BlockSpec((block,), index_map, memory_space=pltpu.SMEM)
 
 
 def _accumulate_chunk(rows_ref, cols_ref, vals_ref, b_ref, o_ref, base, chunk):
     """Unrolled segment-boundary-aware accumulate of one G-nonzero chunk.
 
-    Column ids address rows of ``b_ref`` directly (global for the resident
-    kernel, k-block-local for the K-sharded one).
+    Reads entries ``[base, base + chunk)`` of this step's SMEM stream
+    blocks.  Column ids address rows of ``b_ref`` directly (global for the
+    resident kernel, k-block-local for the K-sharded one).
     """
 
     def contrib(g):
-        c = cols_ref[base + g]
-        brow = pl.load(b_ref, (pl.ds(c, 1), slice(None)))
-        return vals_ref[base + g].astype(jnp.float32) * brow.astype(
-            jnp.float32
-        )
+        brow = b_ref[pl.ds(cols_ref[base + g], 1), :]
+        return vals_ref[base + g] * brow.astype(jnp.float32)
+
+    def flush(row, acc):
+        o_ref[pl.ds(row, 1), :] = o_ref[pl.ds(row, 1), :] + acc
 
     cur_row = rows_ref[base]
     acc = contrib(0)
@@ -83,56 +107,42 @@ def _accumulate_chunk(rows_ref, cols_ref, vals_ref, b_ref, o_ref, base, chunk):
 
         @pl.when(jnp.logical_not(same))
         def _flush(acc=acc, cur_row=cur_row):
-            cur = pl.load(o_ref, (pl.ds(cur_row, 1), slice(None)))
-            pl.store(o_ref, (pl.ds(cur_row, 1), slice(None)), cur + acc)
+            flush(cur_row, acc)
 
         acc = jnp.where(same, acc + contrib(g), contrib(g))
         cur_row = r
-    cur = pl.load(o_ref, (pl.ds(cur_row, 1), slice(None)))
-    pl.store(o_ref, (pl.ds(cur_row, 1), slice(None)), cur + acc)
+    flush(cur_row, acc)
 
 
-def _make_kernel(chunk: int):
-    def _kernel(
-        rows_ref,  # scalar prefetch (nnz_pad,)
-        cols_ref,  # scalar prefetch (nnz_pad,)
-        vals_ref,  # scalar prefetch (nnz_pad,)
-        b_ref,     # (K, bn) resident B n-block
-        o_ref,     # (num_rows_pad, bn) resident fp32 out n-block
-    ):
-        i = pl.program_id(1)
+def _make_kernel(block: int, chunk: int):
+    """Kernel over one ``block``-nonzero step, in ``chunk``-sized unrolls.
 
-        @pl.when(i == 0)
+    Scalar-prefetch refs (the K-sharded tier's ``chunk_kb``), if any, lead
+    the argument list and are read by the index maps only.
+    """
+
+    def _kernel(*refs):
+        rows_ref, cols_ref, vals_ref, b_ref, o_ref = refs[-5:]
+
+        @pl.when(pl.program_id(1) == 0)
         def _init():
             o_ref[...] = jnp.zeros_like(o_ref)
 
-        _accumulate_chunk(
-            rows_ref, cols_ref, vals_ref, b_ref, o_ref, i * chunk, chunk
-        )
+        def sub_chunk(s, carry):
+            _accumulate_chunk(rows_ref, cols_ref, vals_ref, b_ref, o_ref,
+                              s * chunk, chunk)
+            return carry
+
+        jax.lax.fori_loop(0, block // chunk, sub_chunk, 0)
 
     return _kernel
 
 
-def _make_ksharded_kernel(chunk: int):
-    def _kernel(
-        kb_ref,    # scalar prefetch (num_chunks,) chunk -> k-block id
-        rows_ref,  # scalar prefetch (num_chunks*chunk,)
-        cols_ref,  # scalar prefetch (num_chunks*chunk,) k-block-local
-        vals_ref,  # scalar prefetch (num_chunks*chunk,)
-        b_ref,     # (bk, bn) streamed B k-slice of this chunk's k-block
-        o_ref,     # (num_rows_pad, bn) resident fp32 out n-block
-    ):
-        i = pl.program_id(1)
-
-        @pl.when(i == 0)
-        def _init():
-            o_ref[...] = jnp.zeros_like(o_ref)
-
-        _accumulate_chunk(
-            rows_ref, cols_ref, vals_ref, b_ref, o_ref, i * chunk, chunk
-        )
-
-    return _kernel
+def _pad_stream(x: jax.Array, size: int, fill) -> jax.Array:
+    pad = size - x.shape[0]
+    if pad == 0:
+        return x
+    return jnp.concatenate([x, jnp.broadcast_to(fill, (pad,)).astype(x.dtype)])
 
 
 @functools.partial(
@@ -176,39 +186,47 @@ def gather_spmm(
             f"bn={bn}, fp32)",
         )
 
-    # pad the nonzero stream to a chunk multiple; padding entries replicate
+    # pad the nonzero stream to a STEP multiple; padding entries replicate
     # the last row id with value 0 so they accumulate nothing
-    nnz_pad = ((nnz + chunk - 1) // chunk) * chunk
-    if nnz_pad != nnz:
-        pad = nnz_pad - nnz
-        rows = jnp.concatenate([rows, jnp.broadcast_to(rows[-1], (pad,))])
-        cols = jnp.concatenate([cols, jnp.zeros(pad, cols.dtype)])
-        vals = jnp.concatenate([vals, jnp.zeros(pad, vals.dtype)])
+    nnz_pad = pl.cdiv(nnz, STEP) * STEP
+    rows = _pad_stream(rows, nnz_pad, rows[-1])
+    cols = _pad_stream(cols, nnz_pad, 0)
+    vals = _pad_stream(vals.astype(jnp.float32), nnz_pad, 0.0)
     # pad packed output rows to the fp32 sublane multiple
     nr_pad = max(8, ((num_rows + 7) // 8) * 8)
 
-    grid = (n // bn, nnz_pad // chunk)
+    stream = _stream_spec(STEP, lambda j, i: (i,))
     out = pl.pallas_call(
-        _make_kernel(chunk),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((k, bn), lambda j, i, r, c, v: (0, j)),
-            ],
-            out_specs=pl.BlockSpec((nr_pad, bn), lambda j, i, r, c, v: (0, j)),
-        ),
+        _make_kernel(STEP, unroll_factor(chunk)),
+        grid=(n // bn, nnz_pad // STEP),
+        in_specs=[
+            stream, stream, stream,
+            pl.BlockSpec((k, bn), lambda j, i: (0, j)),
+        ],
+        out_specs=pl.BlockSpec((nr_pad, bn), lambda j, i: (0, j)),
         out_shape=jax.ShapeDtypeStruct((nr_pad, n), jnp.float32),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="gather_spmm",
     )(rows, cols, vals, b)
     return out[:num_rows]
 
 
+def _pad_chunks(x: jax.Array, num_chunks: int, block: int, edge: bool):
+    """Re-pad each chunk of a bucketed stream to ``block`` entries.
+
+    ``edge`` repeats the chunk's last entry (row ids: keeps the row run
+    unbroken), otherwise pads with zeros (columns, values: inert).
+    """
+    x = x.reshape(num_chunks, -1)
+    widths = ((0, 0), (0, block - x.shape[1]))
+    return jnp.pad(x, widths, mode="edge" if edge else "constant").reshape(-1)
+
+
 @functools.partial(
-    jax.jit, static_argnames=("num_rows", "bk", "bn", "interpret")
+    jax.jit, static_argnames=("num_rows", "bk", "bn", "chunk", "interpret")
 )
 def gather_spmm_ksharded(
     chunk_kb: jax.Array,  # (num_chunks,) int32, chunk -> k-block id
@@ -220,45 +238,63 @@ def gather_spmm_ksharded(
     num_rows: int,
     bk: int,
     bn: int = 256,
+    chunk: int = 8,
     interpret: bool = False,
 ) -> jax.Array:
     """K-sharded streaming tier: returns packed fp32 output (num_rows, N).
 
     The nonzero stream must be the plan-built k-bucketed layout: sorted by
-    (k-block, row, col), each bucket padded to a chunk multiple (``chunk`` is
-    derived as ``rows.size // chunk_kb.size``), columns local to their
-    k-block.  Only a (bk, bn) slice of B is VMEM-resident per grid step, so
-    K is unbounded by the VMEM budget.
+    (k-block, row, col), each bucket padded to a multiple of the bucket
+    chunk (derived as ``rows.size // chunk_kb.size``), columns local to
+    their k-block.  Plan builders bucket in ``STEP`` chunks, so each chunk
+    is exactly one grid step; other chunk sizes are re-padded to a STEP
+    multiple here.  ``chunk`` is the unroll factor.  Only a (bk, bn) slice
+    of B is VMEM-resident per grid step, so K is unbounded by the VMEM
+    budget.
     """
     num_chunks = chunk_kb.shape[0]
     assert num_chunks >= 1 and rows.shape[0] % num_chunks == 0, (
         rows.shape, chunk_kb.shape
     )
-    chunk = rows.shape[0] // num_chunks
+    bucket = rows.shape[0] // num_chunks
+    block = pl.cdiv(bucket, STEP) * STEP
+    vals = vals.astype(jnp.float32)
+    if block != bucket:
+        rows = _pad_chunks(rows, num_chunks, block, edge=True)
+        cols = _pad_chunks(cols, num_chunks, block, edge=False)
+        vals = _pad_chunks(vals, num_chunks, block, edge=False)
     k, n = b.shape
     assert n % bn == 0, (n, bn)
     k_pad = ((k + bk - 1) // bk) * bk
     if k_pad != k:
         b = jnp.pad(b, ((0, k_pad - k), (0, 0)))
     nr_pad = max(8, ((num_rows + 7) // 8) * 8)
+    if not interpret:
+        # chunk_kb is scalar-prefetched whole (the B index map reads it)
+        from ..core.cost_model import assert_smem_claim, stream_smem_bytes
 
-    grid = (n // bn, num_chunks)
+        assert_smem_claim(
+            stream_smem_bytes(3) + 4 * num_chunks,
+            f"gather_spmm_ksharded chunk map ({num_chunks} chunks)",
+        )
+
+    stream = _stream_spec(block, lambda j, i, kb: (i,))
     out = pl.pallas_call(
-        _make_ksharded_kernel(chunk),
+        _make_kernel(block, unroll_factor(chunk)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
-            grid=grid,
+            num_scalar_prefetch=1,
+            grid=(n // bn, num_chunks),
             in_specs=[
-                pl.BlockSpec((bk, bn), lambda j, i, kb, r, c, v: (kb[i], j)),
+                stream, stream, stream,
+                pl.BlockSpec((bk, bn), lambda j, i, kb: (kb[i], j)),
             ],
-            out_specs=pl.BlockSpec(
-                (nr_pad, bn), lambda j, i, kb, r, c, v: (0, j)
-            ),
+            out_specs=pl.BlockSpec((nr_pad, bn), lambda j, i, kb: (0, j)),
         ),
         out_shape=jax.ShapeDtypeStruct((nr_pad, n), jnp.float32),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="gather_spmm_ksharded",
     )(chunk_kb, rows, cols, vals, b)
     return out[:num_rows]

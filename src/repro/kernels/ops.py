@@ -24,6 +24,7 @@ import jax.numpy as jnp
 
 from . import ref
 from .dense_tile_spmm import dense_tile_spmm
+from .gather_spmm import STEP as FRINGE_STEP  # noqa: F401  (plan builders)
 from .gather_spmm import gather_spmm, gather_spmm_ksharded
 from .sddmm import dense_tile_sddmm, gather_sddmm
 from .structured_spmm import bitmap_tile_spmm, nm_tile_spmm
@@ -44,13 +45,12 @@ def pow2_at_least(n: int) -> int:
 
 
 def effective_chunk(chunk: int | None) -> int:
-    """Per-grid-step nonzero count the pallas fringe kernels actually use.
+    """Unroll factor the pallas fringe kernels use for ``chunk``.
 
-    The kernels unroll their chunk loop in python, so large XLA-oriented
-    values are clamped to a compile-friendly unroll factor.  Plan builders
-    (``prepare``/``prepare_sharded``) MUST pad the k-bucketed stream with
-    this same value — a bucketed stream is only interpretable with the
-    chunk it was padded under — so the clamp lives in exactly one place.
+    The kernels walk each ``FRINGE_STEP``-nonzero grid step in unrolled
+    sub-chunks, so large XLA-oriented values are clamped to a
+    compile-friendly unroll factor.  Plan builders bucket the k-sharded
+    stream in ``FRINGE_STEP`` chunks, independent of this value.
     """
     return min(chunk or 8, 64)
 
@@ -296,7 +296,7 @@ def fringe_spmm(
             )
         return gather_spmm_ksharded(
             kb_chunk, kb_rows, kb_cols, kb_vals, b,
-            num_rows=num_rows, bk=bk, bn=bn,
+            num_rows=num_rows, bk=bk, bn=bn, chunk=effective_chunk(chunk),
             interpret=(impl == "pallas_interpret"),
         )
     return ref.ref_gather_spmm(rows, cols, vals, b, num_rows, chunk=chunk)

@@ -15,8 +15,8 @@ zeros inside occupied tiles:
 
 - **Bitmap packed** (Acc-SpMM-style): per-row occupancy bitmaps
   (bm, ceil(bk/32)) plus a packed value stream (bm, row_cap).  Expansion
-  ranks each set bit with a row-wise cumulative sum and gathers from the
-  packed stream.  General (no pattern assumption); wins when tiles are
+  ranks each set bit by its row-wise prefix count (one MXU matmul) and
+  selects its value from the packed stream.  General (no pattern assumption); wins when tiles are
   mostly empty but row counts are bounded.
 
 Both expansions cost VPU work proportional to bm*bk per tile, traded
@@ -35,8 +35,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from ._compat import tpu_compiler_params
 
 
 def _repeat_cols(x: jax.Array, reps: int) -> jax.Array:
@@ -79,19 +77,27 @@ def _bitmap_expand(words: jax.Array, packed: jax.Array, bk: int) -> jax.Array:
     ``words`` is (bm, ceil(bk/32)) int32 occupancy bits (column c of the
     row lives at bit c%32 of word c//32 — arithmetic shift is sign-safe
     for bit 31 since only bit 0 of the shifted value is read); ``packed``
-    is (bm, row_cap) per-row nonzeros in column order.  Rank each set bit
-    by a row-wise exclusive cumsum, then gather its packed value.
+    is (bm, row_cap) per-row nonzeros in column order.  Each set bit's
+    rank (its row-wise exclusive prefix count) selects its packed value.
+    Mosaic lowers neither cumsum nor a lane gather, so the prefix count is
+    one MXU matmul against a strictly upper-triangular ones matrix (exact:
+    0/1 operands, counts <= bk in fp32) and the gather a static select
+    over the row_cap slots.
     """
     bm, n_words = words.shape
     row_cap = packed.shape[1]
     cols = jax.lax.broadcasted_iota(jnp.int32, (bm, bk), 1)
     word_rep = _repeat_cols(words, 32)[:, :bk]         # (bm, bk)
     bits = (word_rep >> (cols % 32)) & 1
-    rank = jnp.cumsum(bits, axis=1) - bits             # exclusive prefix
-    gathered = jnp.take_along_axis(
-        packed, jnp.clip(rank, 0, row_cap - 1), axis=1
-    )
-    return jnp.where(bits == 1, gathered, 0.0)
+    upper = (jax.lax.broadcasted_iota(jnp.int32, (bk, bk), 0)
+             < jax.lax.broadcasted_iota(jnp.int32, (bk, bk), 1))
+    rank = jnp.dot(bits.astype(jnp.float32), upper.astype(jnp.float32),
+                   preferred_element_type=jnp.float32)  # exclusive prefix
+    rank = jnp.where(bits == 1, rank, -1.0)
+    a = jnp.zeros((bm, bk), jnp.float32)
+    for j in range(row_cap):
+        a = jnp.where(rank == float(j), packed[:, j:j + 1], a)
+    return a
 
 
 def _nm_kernel(n_pat, m_pat, bk, step_window_ref, step_col_ref,
@@ -151,6 +157,11 @@ def nm_tile_spmm(
     assert bk % m_pat == 0, (bk, m_pat)
     gk = bk // m_pat
 
+    if not interpret:
+        from ..core.cost_model import assert_step_metadata_smem
+
+        assert_step_metadata_smem(t_steps, "nm_tile_spmm")
+
     grid = (n // bn, t_steps)
     out = pl.pallas_call(
         functools.partial(_nm_kernel, n_pat, m_pat, bk),
@@ -165,10 +176,11 @@ def nm_tile_spmm(
             out_specs=pl.BlockSpec((bm, bn), lambda j, t, w, c: (w[t], j)),
         ),
         out_shape=jax.ShapeDtypeStruct((num_windows * bm, n), jnp.float32),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="nm_tile_spmm",
     )(step_window, step_col, nm_values, nm_codes, b)
     return out
 
@@ -199,6 +211,11 @@ def bitmap_tile_spmm(
     assert bitmap_words.shape[2] == n_words, (bitmap_words.shape, bk)
     assert bitmap_values.shape[2] == row_cap, (bitmap_values.shape, row_cap)
 
+    if not interpret:
+        from ..core.cost_model import assert_step_metadata_smem
+
+        assert_step_metadata_smem(t_steps, "bitmap_tile_spmm")
+
     grid = (n // bn, t_steps)
     out = pl.pallas_call(
         functools.partial(_bitmap_kernel, bk),
@@ -213,9 +230,10 @@ def bitmap_tile_spmm(
             out_specs=pl.BlockSpec((bm, bn), lambda j, t, w, c: (w[t], j)),
         ),
         out_shape=jax.ShapeDtypeStruct((num_windows * bm, n), jnp.float32),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="bitmap_tile_spmm",
     )(step_window, step_col, bitmap_words, bitmap_values, b)
     return out

@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# ^ MUST precede any jax import: jax locks the device count on first init.
 """Multi-pod dry-run: AOT lower + compile every (arch x shape) cell on the
 production meshes and record memory/cost/collective analyses.
 
@@ -12,6 +9,7 @@ memory_analysis, cost_analysis, collective bytes, and roofline terms.
 No arrays are ever allocated (ShapeDtypeStruct end to end).
 """
 import argparse
+import os
 import json
 import time
 import traceback
@@ -19,6 +17,7 @@ from typing import Any, Dict, Optional
 
 import jax
 
+from ..hostdevices import force_host_device_count
 from ..configs import SHAPES, get_arch, list_archs
 from . import hlo_analysis
 from .mesh import make_production_mesh
@@ -147,6 +146,9 @@ def _write(out_dir: str, name: str, rec: Dict[str, Any]) -> None:
 
 
 def main() -> None:
+    # 512 forced CPU devices for the production meshes; set before jax
+    # creates its backend (importing jax does not)
+    force_host_device_count(os.environ, 512)
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="all")
     ap.add_argument("--shape", default="all")

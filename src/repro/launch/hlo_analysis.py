@@ -11,10 +11,10 @@ import dataclasses
 import re
 from typing import Dict
 
-# TPU v5e-class constants (per brief)
-PEAK_FLOPS_BF16 = 197e12     # FLOP/s per chip
-HBM_BW = 819e9               # bytes/s per chip
-ICI_BW = 50e9                # bytes/s per link
+from ..core.cost_model import DEVICE_PEAKS
+
+# the dry-run compiles for the production TPU v5e meshes
+_V5E = DEVICE_PEAKS["TPU v5 lite"]
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "f16": 2, "bf16": 2,
@@ -95,9 +95,9 @@ def roofline_terms(
     flops_pd: float, bytes_pd: float, coll_bytes_pd: float
 ) -> RooflineTerms:
     return RooflineTerms(
-        compute_s=flops_pd / PEAK_FLOPS_BF16,
-        memory_s=bytes_pd / HBM_BW,
-        collective_s=coll_bytes_pd / ICI_BW,
+        compute_s=flops_pd / _V5E.flops_per_s,
+        memory_s=bytes_pd / _V5E.bytes_per_s,
+        collective_s=coll_bytes_pd / _V5E.ici_bytes_per_s,
         flops_per_device=flops_pd,
         bytes_per_device=bytes_pd,
         collective_bytes_per_device=coll_bytes_pd,

@@ -12,19 +12,14 @@ from typing import Dict
 import jax
 
 
-def _mesh_kwargs(n_axes: int) -> Dict:
-    """``axis_types`` only exists on newer jax; older releases default to
-    Auto, so omitting it is equivalent there."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
+def _auto_axes(n_axes: int) -> Dict:
+    return {"axis_types": (jax.sharding.AxisType.Auto,) * n_axes}
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_mesh_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes, **_auto_axes(len(axes)))
 
 
 def mesh_axis_sizes(mesh) -> Dict[str, int]:
@@ -34,7 +29,7 @@ def mesh_axis_sizes(mesh) -> Dict[str, int]:
 def make_debug_mesh(n_data: int = 2, n_model: int = 4):
     """Small mesh for CPU tests (requires forced host device count)."""
     return jax.make_mesh((n_data, n_model), ("data", "model"),
-                         **_mesh_kwargs(2))
+                         **_auto_axes(2))
 
 
 def make_spmm_mesh(n_shards: int = 0, axis_name: str = "data"):
@@ -53,4 +48,4 @@ def make_spmm_mesh(n_shards: int = 0, axis_name: str = "data"):
             "on CPU, force more with XLA_FLAGS="
             f"--xla_force_host_platform_device_count={n}"
         )
-    return jax.make_mesh((n,), (axis_name,), **_mesh_kwargs(1))
+    return jax.make_mesh((n,), (axis_name,), **_auto_axes(1))

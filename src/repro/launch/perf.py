@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# ^ MUST precede any jax import (same contract as dryrun.py).
 """Perf hillclimb driver (EXPERIMENTS.md §Perf).
 
 Runs the three selected cells through their hypothesis->change->measure
@@ -11,12 +8,14 @@ deltas vs the recorded baseline go into the §Perf log.
     PYTHONPATH=src python -m repro.launch.perf [--cell qwen-decode] [--iter N]
 """
 import argparse
+import os
 import json
 from typing import Any, Dict, Optional
 
 import jax.numpy as jnp
 
 from ..distributed.sharding import AxisRules
+from ..hostdevices import force_host_device_count
 from .dryrun import run_cell
 
 SERVE_TP_ONLY = AxisRules(batch_axes=("data",), fsdp_axes=(), tp_axis="model")
@@ -179,6 +178,8 @@ def run_iteration(cell_key: str, idx: int, out_dir: str = "artifacts/perf"):
 
 
 def main():
+    # the cells compile on the dry-run's forced 512-device CPU mesh
+    force_host_device_count(os.environ, 512)
     ap = argparse.ArgumentParser()
     ap.add_argument("--cell", default="all",
                     choices=["all"] + list(HILLCLIMB))

@@ -16,12 +16,15 @@ modeled FLOPs/bytes per engine path); output is:
   its overlap work on.
 
 Compile/trace calls are excluded by default (``traced`` records measure
-XLA's compiler, not the engines).  Everything here is plain aggregation
+XLA's compiler, not the engines).  A record without peaks (a device kind
+the peak table does not list, such as the CPU) is *unpriced*: its modeled
+work is still reported, but its bound, shares, attributed time and
+utilization are None — never computed against another device's peaks.  Everything here is plain aggregation
 over host-side records — no jax, no imports from the layers above.
 """
 from __future__ import annotations
 
-from typing import Any, Dict, Iterable, List
+from typing import Any, Dict, Iterable, List, Optional
 
 from .metrics import format_sample
 from .profile import PATHS, DispatchRecord
@@ -72,6 +75,7 @@ def roofline_attribution(
                 "_waste_sum": 0.0,
                 "_waste_n": 0,
             }
+        priced = bool(row["peaks"])
         row["calls"] += 1
         row["measured_us"] += rec.measured_us
         if "matrix_format" in rec.attrs:
@@ -86,17 +90,26 @@ def roofline_attribution(
             acc = row["paths"][p]
             acc["flops"] += terms["flops"]
             acc["bytes"] += terms["bytes"]
-            acc["bound_us"] += _path_bound_us(terms, rec.peaks)
+            if priced:
+                acc["bound_us"] += _path_bound_us(terms, rec.peaks)
 
     out_rows: List[Dict[str, Any]] = []
     total = {p: {"bound_us": 0.0, "attributed_us": 0.0, "flops": 0.0,
                  "bytes": 0.0} for p in PATHS}
     total_measured = 0.0
+    priced_measured = 0.0
     for key in sorted(rows):
         row = rows[key]
         measured = row["measured_us"]
+        priced = bool(row["peaks"])
         bound_total = sum(p["bound_us"] for p in row["paths"].values())
         for p, acc in row["paths"].items():
+            total[p]["flops"] += acc["flops"]
+            total[p]["bytes"] += acc["bytes"]
+            if not priced:
+                acc.update(bound_us=None, share=None, attributed_us=None,
+                           bound="unpriced")
+                continue
             # measured wall covers the whole fused dispatch; attribute it
             # to engine paths proportionally to each path's modeled bound
             share = acc["bound_us"] / bound_total if bound_total > 0 else 0.0
@@ -105,10 +118,10 @@ def roofline_attribution(
             acc["bound"] = _bound_kind(acc, row["peaks"])
             total[p]["bound_us"] += acc["bound_us"]
             total[p]["attributed_us"] += acc["attributed_us"]
-            total[p]["flops"] += acc["flops"]
-            total[p]["bytes"] += acc["bytes"]
         row["mean_us"] = measured / row["calls"] if row["calls"] else 0.0
-        row["utilization"] = bound_total / measured if measured > 0 else 0.0
+        row["utilization"] = _ratio(bound_total, measured) if priced else None
+        if priced:
+            priced_measured += measured
         # padding waste of the matrix path's streamed tiles (from the plan
         # stats, via the dispatch attrs): structured payloads model fewer
         # bytes for the same waste, which shows up as a higher utilization
@@ -119,18 +132,35 @@ def roofline_attribution(
         out_rows.append(row)
 
     overall_bound = sum(t["bound_us"] for t in total.values())
+    any_priced = any(row["peaks"] for row in out_rows)
     for t in total.values():
-        t["share"] = (t["bound_us"] / overall_bound
-                      if overall_bound > 0 else 0.0)
+        if any_priced:
+            t["share"] = (t["bound_us"] / overall_bound
+                          if overall_bound > 0 else 0.0)
+        else:
+            t.update(bound_us=None, attributed_us=None, share=None)
     return {
         "rows": out_rows,
         "matrix_path": total["matrix"],
         "fringe_path": total["fringe"],
         "measured_us_total": total_measured,
-        "utilization": (overall_bound / total_measured
-                        if total_measured > 0 else 0.0),
+        "utilization": (_ratio(overall_bound, priced_measured)
+                        if any_priced else None),
         "skipped_traced": skipped_traced,
     }
+
+
+def _ratio(num: float, den: float) -> float:
+    return num / den if den > 0 else 0.0
+
+
+def _pct(x: Optional[float], width: int) -> str:
+    """``x`` as a percentage, or '-' when unpriced."""
+    return f"{'-':>{width}}" if x is None else f"{100.0 * x:>{width}.1f}%"
+
+
+def _us(x: Optional[float]) -> str:
+    return "-" if x is None else f"{x:.1f}"
 
 
 def format_report(attr: Dict[str, Any]) -> str:
@@ -138,7 +168,7 @@ def format_report(attr: Dict[str, Any]) -> str:
     lines = [
         "engine-path roofline attribution "
         f"(measured {attr['measured_us_total']:.1f} us, "
-        f"utilization {100.0 * attr['utilization']:.1f}%)",
+        f"utilization {_pct(attr['utilization'], 0).strip()})",
         f"{'op':<10} {'tier':<10} {'sig':<12} {'calls':>6} "
         f"{'mean_us':>10} {'matrix%':>8} {'fringe%':>8} {'util%':>7} "
         f"{'fmt':<8} {'waste%':>7}",
@@ -148,9 +178,9 @@ def format_report(attr: Dict[str, Any]) -> str:
         lines.append(
             f"{row['op']:<10} {row['tier']:<10} {row['sig']:<12} "
             f"{row['calls']:>6} {row['mean_us']:>10.1f} "
-            f"{100.0 * row['paths']['matrix']['share']:>7.1f}% "
-            f"{100.0 * row['paths']['fringe']['share']:>7.1f}% "
-            f"{100.0 * row['utilization']:>6.1f}% "
+            f"{_pct(row['paths']['matrix']['share'], 7)} "
+            f"{_pct(row['paths']['fringe']['share'], 7)} "
+            f"{_pct(row['utilization'], 6)} "
             f"{row.get('matrix_format') or '-':<8} "
             + (f"{100.0 * waste:>6.1f}%" if waste is not None
                else f"{'-':>7}")
@@ -159,9 +189,9 @@ def format_report(attr: Dict[str, Any]) -> str:
         t = attr[f"{path}_path"]
         lines.append(
             f"{path}-path: modeled {t['flops']:.3g} FLOPs / "
-            f"{t['bytes']:.3g} B, bound {t['bound_us']:.1f} us, "
-            f"attributed {t['attributed_us']:.1f} us "
-            f"({100.0 * t['share']:.1f}% of modeled cost)"
+            f"{t['bytes']:.3g} B, bound {_us(t['bound_us'])} us, "
+            f"attributed {_us(t['attributed_us'])} us "
+            f"({_pct(t['share'], 0).strip()} of modeled cost)"
         )
     return "\n".join(lines)
 
@@ -186,6 +216,8 @@ def roofline_prometheus(attr: Dict[str, Any]) -> str:
                                    row["calls"]))
     lines.append("# TYPE repro_roofline_utilization gauge")
     for row in attr["rows"]:
+        if row["utilization"] is None:
+            continue  # unpriced device: no share to export
         base = {"op": row["op"], "tier": row["tier"], "sig": row["sig"]}
         lines.append(format_sample("repro_roofline_utilization", base,
                                    row["utilization"]))
@@ -205,11 +237,15 @@ def roofline_prometheus(attr: Dict[str, Any]) -> str:
         lines.append(f"# TYPE {metric} gauge")
         for row in attr["rows"]:
             for p in PATHS:
+                if row["paths"][p][field] is None:
+                    continue
                 labels = {"op": row["op"], "tier": row["tier"],
                           "sig": row["sig"], "path": p}
                 lines.append(format_sample(
                     metric, labels, row["paths"][p][field]))
         for p in PATHS:
+            if attr[f"{p}_path"][field] is None:
+                continue
             lines.append(format_sample(
                 metric, {"op": "_all", "tier": "_all", "sig": "_all",
                          "path": p},

@@ -6,11 +6,15 @@ import pytest
 from repro.core.cost_model import (
     DELTA_BASE_NNZ_FLOOR,
     DELTA_MAX_FRACTION,
+    FRINGE_STEP,
     FRINGE_VMEM_BUDGET,
+    SMEM_BUDGET,
+    SMEM_BYTES,
     EngineCostModel,
     default_cost_model,
     fringe_ksharded_bytes,
     fringe_resident_bytes,
+    fringe_smem_bytes,
     ksharded_bk_cap,
     select_fringe_tier,
     should_compact,
@@ -102,6 +106,26 @@ def test_fringe_tier_respects_budget_override():
     assert select_fringe_tier(64, 16, 128)[0] == "resident"
     assert select_fringe_tier(64, 16, 128, vmem_budget=20_000)[0] == "ksharded"
     assert select_fringe_tier(64, 16, 128, vmem_budget=4_096)[0] == "xla"
+
+
+@pytest.mark.parametrize("nnz", [0, 1, 80_000, 10**6, 10**8, 3 * 10**8])
+def test_fringe_tier_never_claims_more_smem_than_the_chip(nnz):
+    """Whatever the fringe's size, the picked tier's SMEM claim fits: the
+    streamed nonzero blocks are fixed, and only the K-sharded chunk map
+    (4 B per STEP-entry chunk, scalar-prefetched whole) grows with nnz."""
+    for k in (1024, 20_000, 169_344, 2_000_000):
+        for num_rows in (100, 2000, 8192):
+            tier, bk = select_fringe_tier(k, num_rows, 256, nnz=nnz)
+            assert fringe_smem_bytes(tier, k, bk, nnz) <= SMEM_BUDGET < SMEM_BYTES
+
+
+def test_fringe_tier_demotes_ksharded_when_chunk_map_overflows_smem():
+    k, rows, bn = 20_000, 100, 256
+    assert select_fringe_tier(k, rows, bn, nnz=10**6)[0] == "ksharded"
+    # ~250M nonzeros make a chunk map larger than the SMEM budget
+    huge = (SMEM_BUDGET // 4) * FRINGE_STEP
+    assert fringe_smem_bytes("ksharded", k, 2048, huge) > SMEM_BUDGET
+    assert select_fringe_tier(k, rows, bn, nnz=huge) == ("xla", 0)
 
 
 # --- bug regression: measure() must synchronize async dispatch ------------

@@ -4,9 +4,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core.plan_ir import bucket_fringe_kblocks
 from repro.kernels import ops, ref
 from repro.kernels.dense_tile_spmm import dense_tile_spmm
-from repro.kernels.gather_spmm import gather_spmm, gather_spmm_ksharded
+from repro.kernels.gather_spmm import STEP, gather_spmm, gather_spmm_ksharded
+from repro.kernels.sddmm import gather_sddmm
 
 
 def _block_stream(rng, num_windows, max_blocks, bm, bk, k_blocks, dtype):
@@ -201,3 +203,84 @@ def test_zero_value_padding_steps_are_noops():
     out = dense_tile_spmm(sw, sc, vals, b, num_windows=1, bm=8, bk=8, bn=128,
                           interpret=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(b[:8]), rtol=1e-6)
+
+
+# --- chunk-streamed fringe kernels: the nonzero stream rides SMEM blocks ----
+# of STEP entries per grid step, so a fringe past the ~80k-nonzero ceiling
+# of a wholly scalar-prefetched stream (1 MiB of SMEM) still fits
+
+OLD_SMEM_CEILING_NNZ = 80_000
+
+
+def _sorted_fringe(rng, num_rows, k, nnz):
+    rows = np.sort(rng.randint(0, num_rows, nnz)).astype(np.int32)
+    cols = rng.randint(0, k, nnz).astype(np.int32)
+    vals = rng.randn(nnz).astype(np.float32)
+    return jnp.asarray(rows), jnp.asarray(cols), jnp.asarray(vals)
+
+
+STREAM_CASES = [(nnz, chunk) for nnz in (1, STEP - 1, STEP, 2 * STEP + 7)
+                for chunk in (1, 5, 8, 64)]
+STREAM_CASES.append((OLD_SMEM_CEILING_NNZ + 4321, 8))
+
+
+@pytest.mark.parametrize("nnz,chunk", STREAM_CASES)
+def test_gather_spmm_streamed_matches_ref(nnz, chunk):
+    """Every unroll factor walks whole STEP blocks, ragged tails included."""
+    rng = np.random.RandomState(nnz % 97 + chunk)
+    num_rows, k = 300, 512
+    rows, cols, vals = _sorted_fringe(rng, num_rows, k, nnz)
+    b = jnp.asarray(rng.randn(k, 128).astype(np.float32))
+    out = gather_spmm(rows, cols, vals, b, num_rows=num_rows, bn=128,
+                      chunk=chunk, interpret=True)
+    expect = ref.ref_gather_spmm(rows, cols, vals, b, num_rows)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
+                               rtol=1e-4, atol=1e-4)
+
+
+KSHARDED_CASES = [(nnz, bucket, chunk)
+                  for nnz, bucket in ((700, 3), (700, 8), (3 * STEP + 11, STEP))
+                  for chunk in (1, 8, 64)]
+KSHARDED_CASES.append((OLD_SMEM_CEILING_NNZ + 4321, STEP, 8))
+
+
+@pytest.mark.parametrize("nnz,bucket,chunk", KSHARDED_CASES)
+def test_gather_spmm_ksharded_streamed_matches_refs(nnz, bucket, chunk):
+    """Plan-bucketed streams (STEP buckets: one chunk per grid step) and
+    hand-sized buckets (re-padded to STEP inside the wrapper) both match
+    the k-blocked oracle and the dense answer."""
+    rng = np.random.RandomState(nnz % 89 + bucket + chunk)
+    num_rows, k, bk = 200, 1000, 128
+    rows, cols, vals = (np.asarray(x) for x in
+                        _sorted_fringe(rng, num_rows, k, nnz))
+    k_pad = -(-k // bk) * bk
+    kb_chunk, kb_rows, kb_cols, kb_vals, _ = bucket_fringe_kblocks(
+        rows, cols, vals, k_pad, bk, bucket)
+    b = jnp.asarray(rng.randn(k, 128).astype(np.float32))
+    args = tuple(jnp.asarray(x) for x in (kb_chunk, kb_rows, kb_cols, kb_vals))
+    out = gather_spmm_ksharded(*args, b, num_rows=num_rows, bk=bk, bn=128,
+                               chunk=chunk, interpret=True)
+    oracle = ref.ref_gather_spmm_kblocked(*args, b, num_rows, bk)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(oracle),
+                               rtol=1e-4, atol=1e-4)
+    dense = ref.ref_gather_spmm(jnp.asarray(rows), jnp.asarray(cols),
+                                jnp.asarray(vals), b, num_rows)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(dense),
+                               rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("nnz,chunk", [(1, 8), (STEP + 3, 1), (STEP + 3, 32),
+                                       (OLD_SMEM_CEILING_NNZ + 4321, 32)])
+def test_gather_sddmm_streamed_matches_ref(nnz, chunk):
+    """The sddmm gather's row/col id blocks stream through SMEM too; dots
+    come back in input order whatever the unroll factor."""
+    rng = np.random.RandomState(nnz % 83 + chunk)
+    m, k, d = 300, 400, 96
+    rows = jnp.asarray(rng.randint(0, m, nnz).astype(np.int32))
+    cols = jnp.asarray(rng.randint(0, k, nnz).astype(np.int32))
+    x = jnp.asarray(rng.randn(m, d).astype(np.float32))
+    yt = jnp.asarray(rng.randn(k, d).astype(np.float32))
+    out = gather_sddmm(rows, cols, x, yt, chunk=chunk, interpret=True)
+    expect = ref.ref_gather_sddmm(rows, cols, x, yt)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
+                               rtol=1e-4, atol=1e-4)

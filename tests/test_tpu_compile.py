@@ -1,0 +1,135 @@
+"""Compile every main-path Pallas kernel for a described TPU v5e.
+
+Interpret mode (the rest of the suite) cannot see what the chip's compiler
+refuses: blocks not aligned to the tiling, more VMEM or SMEM than a kernel
+may use.  The TPU compiler is installed, and it compiles for a chip that is
+described rather than attached, so these tests run on a CPU-only host.
+Nothing is executed; a passing compile is not a chip run.
+
+Shapes are the widths ``chip_smoke.py`` runs at — the ogbn-arxiv-scale
+graph (169,343 nodes, feature width 128), the ``wiki-RfA`` / ``ogbn-arxiv``
+stand-ins and ``dlmc-nm-1-32`` at width 256 — plus fringes of 2^20
+nonzeros, past the ~80k-nonzero ceiling a wholly prefetched stream hit.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.dense_tile_spmm import dense_tile_spmm
+from repro.kernels.gather_spmm import STEP, gather_spmm, gather_spmm_ksharded
+from repro.kernels.sddmm import dense_tile_sddmm, gather_sddmm
+from repro.kernels.structured_spmm import bitmap_tile_spmm, nm_tile_spmm
+
+I32, F32 = jnp.int32, jnp.float32
+BIG_FRINGE = 1 << 20
+
+# ogbn-arxiv at its published size, as chip_smoke.py phase a prepares it
+ARXIV_K = 169_344          # 169,343 padded to the bk=64 multiple
+ARXIV_STEPS = 2_646        # tile steps of its matrix path (bm=128, bk=64)
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """One chip of a described v5e:2x2, with the persistent cache off.
+
+    A compile for a described chip is written to the cache but cannot be
+    read back without the chip, so the cache stays off around these.
+    """
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        try:
+            topo = topologies.get_topology_desc(
+                platform="tpu", topology_name="v5e:2x2")
+        except Exception as e:  # noqa: BLE001 — any failure means "cannot"
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        yield SingleDeviceSharding(topo.devices[0])
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was_on)
+        compilation_cache.reset_cache()
+
+
+def _compile(fn, one_chip, shapes, **static):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    compiled = fn.lower(*args, **static).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+def _gather(k, rows, bn, nnz):
+    return dict(
+        fn=gather_spmm,
+        shapes=[((nnz,), I32), ((nnz,), I32), ((nnz,), F32), ((k, bn), F32)],
+        static=dict(num_rows=rows, bn=bn, chunk=8),
+    )
+
+
+def _ksharded(k, bk, rows, bn, nnz):
+    n_chunks = -(-nnz // STEP) + -(-k // bk)  # STEP-padded k-buckets
+    stream = n_chunks * STEP
+    return dict(
+        fn=gather_spmm_ksharded,
+        shapes=[((n_chunks,), I32), ((stream,), I32), ((stream,), I32),
+                ((stream,), F32), ((k, bn), F32)],
+        static=dict(num_rows=rows, bk=bk, bn=bn, chunk=8),
+    )
+
+
+CASES = {
+    # phase a matrix path: the largest tile stream the smoke builds
+    "dense_tile_spmm-arxiv": dict(
+        fn=dense_tile_spmm,
+        shapes=[((ARXIV_STEPS,), I32), ((ARXIV_STEPS,), I32),
+                ((ARXIV_STEPS, 128, 64), F32), ((ARXIV_K, 128), F32)],
+        static=dict(num_windows=1, bm=128, bk=64, bn=128),
+    ),
+    # phase b: wiki-RfA's resident fringe, and a 2^20-nonzero one
+    "gather_spmm-wiki-RfA": _gather(4096, 3624, 256, 34_875),
+    "gather_spmm-1M": _gather(4096, 3624, 256, BIG_FRINGE),
+    # phase b: the ogbn-arxiv stand-in's K-sharded fringe (bk=2048)
+    "gather_spmm_ksharded-ogbn-arxiv": _ksharded(8192, 2048, 8181, 256,
+                                                 66_373),
+    "gather_spmm_ksharded-1M": _ksharded(ARXIV_K, 2048, 2048, 128,
+                                         BIG_FRINGE),
+    # phase c: dlmc-nm-1-32, 1:32 packed tiles (gk = bk/m = 2)
+    "nm_tile_spmm-dlmc-nm-1-32": dict(
+        fn=nm_tile_spmm,
+        shapes=[((2048,), I32), ((2048,), I32), ((2048, 128, 2), F32),
+                ((2048, 128, 2), I32), ((4096, 256), F32)],
+        static=dict(num_windows=32, bm=128, bk=64, bn=256, n_pat=1,
+                    m_pat=32),
+    ),
+    "bitmap_tile_spmm-dlmc": dict(
+        fn=bitmap_tile_spmm,
+        shapes=[((2048,), I32), ((2048,), I32), ((2048, 128, 2), I32),
+                ((2048, 128, 8), F32), ((4096, 256), F32)],
+        static=dict(num_windows=32, bm=128, bk=64, bn=256, row_cap=8),
+    ),
+    # phase a sddmm matrix path: one window panel against Y's k-blocks
+    "dense_tile_sddmm-arxiv": dict(
+        fn=dense_tile_sddmm,
+        shapes=[((ARXIV_STEPS,), I32), ((ARXIV_STEPS,), I32),
+                ((128, 128), F32), ((128, ARXIV_K), F32)],
+        static=dict(bm=128, bk=64),
+    ),
+    "gather_sddmm-1M": dict(
+        fn=gather_sddmm,
+        shapes=[((BIG_FRINGE,), I32), ((BIG_FRINGE,), I32),
+                ((4096, 128), F32), ((4096, 128), F32)],
+        static=dict(chunk=8),
+    ),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_kernel_compiles_for_v5e(case, one_chip):
+    spec = CASES[case]
+    _compile(spec["fn"], one_chip, spec["shapes"], **spec["static"])
