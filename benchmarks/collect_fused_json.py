@@ -60,7 +60,7 @@ def main(argv=None) -> None:
     p.add_argument("--telemetry-out", default=None, metavar="PATH",
                    help="run the exec panel with SpmmConfig.telemetry "
                         "enabled and dump the repro.obs snapshot (metrics "
-                        "+ traces + roofline attribution) as JSON")
+                        "+ traces + phase spans) as JSON")
     args = p.parse_args(argv)
     telemetry = args.telemetry_out is not None
 
@@ -133,8 +133,6 @@ def main(argv=None) -> None:
         snap["prometheus"] = obs.prometheus_text()
         with open(args.telemetry_out, "w") as f:
             json.dump(snap, f, indent=2)
-        from repro.obs import format_report
-        print(format_report(snap["roofline"]))
 
 
 if __name__ == "__main__":

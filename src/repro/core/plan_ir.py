@@ -203,9 +203,11 @@ class SpmmConfig:
     #                   core stream does not satisfy it
     #   "bitmap"      — force the bitmap-compressed payload
     structure_hint: Optional[Any] = None
-    # host-side telemetry (repro.obs): per-dispatch roofline profiling and
-    # per-request tracing.  Never part of signature() — toggling it must
-    # not retrace, re-dispatch, or change any numeric output.
+    # host-side telemetry (repro.obs): record per-request traces in the
+    # obs.TRACES ring.  It makes no call synchronize, and it is never part
+    # of signature() — toggling it must not retrace, re-dispatch, or
+    # change any numeric output.  The phase spans and scopes that a
+    # jax.profiler trace shows need no flag.
     telemetry: bool = False
 
 

@@ -10,7 +10,6 @@ working unchanged.
 from __future__ import annotations
 
 import dataclasses
-import functools
 import time
 import warnings
 import zlib
@@ -21,14 +20,13 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core import plan_ir, tuner
-from ..core.cost_model import device_peaks, matrix_payload_bytes
 from ..core.plan_ir import (
     NeutronPlan, ShardedPlan, SpmmConfig, build_sddmm_maps, gather_rows,
     permute_pad_b, plan_leaves, sddmm_body_leaves, validate_rhs,
 )
 from ..errors import DispatchError, KernelLoweringError, PlanBuildError
 from ..kernels import ops
-from ..obs import PROFILER
+from ..obs import span
 from . import cache as _cache
 from .cache import (  # noqa: F401  (re-exported test hooks)
     dispatch_count, fused_trace_count, sharded_trace_count,
@@ -36,21 +34,6 @@ from .cache import (  # noqa: F401  (re-exported test hooks)
 )
 from .health import HEALTH
 from .pipeline import build_delta_only_executor, build_executor
-
-@functools.cache
-def _device_peaks() -> dict:
-    """Roofline ceilings the telemetry profiler reports modeled work against.
-
-    Looked up by the dispatch device's kind in ``cost_model.DEVICE_PEAKS``
-    (obs itself never imports the cost model, so they ride on every
-    record).  An unlisted kind — the CPU among them — gets no peaks, and
-    the roofline report then computes no share for it.
-    """
-    peaks = device_peaks(jax.devices()[0].device_kind)
-    if peaks is None:
-        return {}
-    return {"flops_per_s": peaks.flops_per_s, "bytes_per_s": peaks.bytes_per_s}
-
 
 def _apply_cache_capacity(config: SpmmConfig) -> None:
     if config.executor_cache_capacity is not None:
@@ -86,42 +69,20 @@ def _tuned_densify(plan) -> float | None:
 
 
 def _sig_key(sig) -> str:
-    """Short deterministic key for a plan signature (telemetry label)."""
+    """Short deterministic key for a plan signature (warning text)."""
     return f"{zlib.crc32(repr(sig).encode()):08x}"
 
 
-def _maybe_profiled(fn, args, *, kind, sig, tier, prof):
-    """Invoke the executor, measuring it when telemetry asked for it.
-
-    ``prof is None`` (telemetry off) is the production path: the executor
-    is called exactly as before — no synchronization, no clock reads.
-    With telemetry on, the call is timed with the ``timed_best_of``
-    discipline (block on the result before reading the clock, so under
-    JAX async dispatch the measurement covers the compute, not the
-    enqueue) and one :class:`repro.obs.DispatchRecord` is written joining
-    the measurement with the caller's modeled FLOP/byte terms.  Host-side
-    only: the same single ``fn(*args)`` dispatch either way, and sig/
-    cache keys never see the telemetry flag.
-    """
-    if prof is None:
+def _launch(fn, args, lookup: span):
+    """End the caller's ``repro.lookup`` span and dispatch the executor
+    under ``repro.launch``: the one ``fn(*args)``, never synchronized."""
+    lookup.close()
+    with span("launch"):
         return fn(*args)
-    traces0 = _cache.fused_trace_count() + _cache.sharded_trace_count()
-    t0 = time.perf_counter()
-    out = fn(*args)
-    jax.block_until_ready(out)
-    measured_us = (time.perf_counter() - t0) * 1e6
-    traced = (_cache.fused_trace_count()
-              + _cache.sharded_trace_count()) > traces0
-    PROFILER.record(
-        op=prof["op"], tier=str(tier), sig_key=_sig_key(sig), kind=kind,
-        measured_us=measured_us, traced=traced, batch=prof.get("batch"),
-        terms=prof["terms"], peaks=_device_peaks(), attrs=prof.get("attrs"),
-    )
-    return out
 
 
 def _guarded_call(sig, config: SpmmConfig, make_fn, args, kind: str, key_of,
-                  prof=None):
+                  lookup: span):
     """Build + dispatch with health gating and degrade-to-XLA fallback.
 
     ``make_fn(sig) -> fn`` builds (or fetches) the executor for a
@@ -137,23 +98,21 @@ def _guarded_call(sig, config: SpmmConfig, make_fn, args, kind: str, key_of,
     successful synchronous dispatch (async device-side errors surfacing at
     a later block) are out of scope here.
 
-    ``prof`` (built by the entry points only when ``config.telemetry``)
-    carries the op name and modeled per-engine-path FLOP/byte terms for
-    the roofline profiler; every dispatch branch reports the tier it
-    actually ran on.
+    ``lookup`` is the entry point's open ``repro.lookup`` span: it holds
+    the entry's validation and signature work and the executor lookup
+    here, and ends where the executor launches.  After a failed
+    accelerated launch the fallback's lookup opens a second one.
     """
     impl = plan_ir.sig_impl(sig)
     if impl is None or impl == "xla":
         fn = make_fn(sig)
         _cache.record_dispatch(kind, key_of(sig))
-        return _maybe_profiled(fn, args, kind=kind, sig=sig,
-                               tier=impl or "xla", prof=prof)
+        return _launch(fn, args, lookup)
     if HEALTH.should_try_accel(sig):
         try:
             fn = make_fn(sig)
             _cache.record_dispatch(kind, key_of(sig))
-            out = _maybe_profiled(fn, args, kind=kind, sig=sig, tier=impl,
-                                  prof=prof)
+            out = _launch(fn, args, lookup)
             HEALTH.record_success(sig)
             return out
         except Exception as err:  # noqa: BLE001 — any accel failure degrades
@@ -172,100 +131,19 @@ def _guarded_call(sig, config: SpmmConfig, make_fn, args, kind: str, key_of,
                 )
     fsig = plan_ir.xla_fallback_sig(sig)
     HEALTH.record_fallback(sig)
+    if lookup.closed:  # the accelerated launch failed after its lookup
+        lookup = span("lookup").__enter__()
     try:
         fn = make_fn(fsig)
         _cache.record_dispatch(kind + ":degraded", key_of(fsig))
-        return _maybe_profiled(fn, args, kind=kind + ":degraded", sig=fsig,
-                               tier="xla", prof=prof)
+        return _launch(fn, args, lookup)
     except Exception as err:
         raise DispatchError(
             f"dispatch failed on every tier (accel impl={impl!r} degraded, "
             f"then XLA fallback raised: {err})"
         ) from err
-
-
-# --- modeled roofline terms (telemetry only) ---------------------------------
-#
-# Modeled FLOPs/bytes are *lower bounds* on each engine path's work, in the
-# cost model's own currency (cost_matrix/cost_vector): the matrix path as
-# dense (bm x bk) tile matmuls against streamed B blocks, the fringe path
-# as per-nonzero gather dot-products.  Sharded plans lack per-path stats
-# (stats carry shard totals only), so their whole dispatch models on the
-# matrix path from total nnz.
-
-
-def _spmm_prof(plan, b: jax.Array):
-    config = plan.config
-    if not getattr(config, "telemetry", False):
-        return None
-    stats = plan.stats_dict
-    n = int(b.shape[-1])
-    batch = int(b.shape[0]) if b.ndim == 3 else None
-    scale = float(batch or 1)
-    fringe_nnz = int(stats.get("fringe_nnz", 0))
-    num_steps = int(stats.get("num_steps", 0))
-    num_windows = int(stats.get("num_windows", 0))
-    mfmt = str(stats.get("matrix_format", "general"))
-    fparams = tuple(stats.get("format_params", (0, 0)))
-    if num_steps:
-        mat_flops = 2.0 * num_steps * config.bm * config.bk * n
-        # the A payload models at the format the plan actually streams —
-        # packed bytes for nm/bitmap, the padded dense tiles for general —
-        # so roofline rows show the padding-waste reduction directly
-        a_bytes = matrix_payload_bytes(
-            mfmt, num_steps, config.bm, config.bk,
-            nm_pattern=fparams if mfmt == "nm" else None,
-            row_cap=int(fparams[1]) if mfmt == "bitmap" else 0,
-        )
-        mat_bytes = (a_bytes
-                     + (num_steps * config.bk * n
-                        + num_windows * config.bm * n) * 4.0)
-    else:
-        core_nnz = max(_plan_nnz(plan) - fringe_nnz, 0)
-        mat_flops = 2.0 * core_nnz * n
-        mat_bytes = core_nnz * (12.0 + 4.0 * n)
-    return {
-        "op": "spmm", "batch": batch,
-        "terms": {
-            "matrix": {"flops": mat_flops * scale,
-                       "bytes": mat_bytes * scale},
-            "fringe": {"flops": 2.0 * fringe_nnz * n * scale,
-                       "bytes": fringe_nnz * (12.0 + 4.0 * n) * scale},
-        },
-        "attrs": {
-            "padding_waste": float(stats.get("padding_waste", 0.0)),
-            "matrix_format": mfmt,
-        },
-    }
-
-
-def _sddmm_prof(config, nnz: int, nnz_f: int, d: int, batch):
-    if not getattr(config, "telemetry", False):
-        return None
-    scale = float(batch or 1)
-    core = max(int(nnz) - int(nnz_f), 0)
-    return {
-        "op": "sddmm", "batch": batch,
-        "terms": {
-            "matrix": {"flops": 2.0 * core * d * scale,
-                       "bytes": core * (8.0 * d + 4.0) * scale},
-            "fringe": {"flops": 2.0 * int(nnz_f) * d * scale,
-                       "bytes": int(nnz_f) * (8.0 * d + 12.0) * scale},
-        },
-    }
-
-
-def _spspmm_prof(config, n_exp: int, nnz_c: int):
-    if not getattr(config, "telemetry", False):
-        return None
-    # expansion products + segment sum: pure vector-engine work
-    return {
-        "op": "spspmm", "batch": None,
-        "terms": {
-            "fringe": {"flops": 2.0 * int(n_exp),
-                       "bytes": 12.0 * int(n_exp) + 4.0 * int(nnz_c)},
-        },
-    }
+    finally:
+        lookup.close()
 
 
 def execute(plan: NeutronPlan, b: jax.Array) -> jax.Array:
@@ -280,16 +158,17 @@ def execute(plan: NeutronPlan, b: jax.Array) -> jax.Array:
     health gate: a kernel failure degrades to the XLA tier (bit-identical)
     instead of raising — see :mod:`repro.exec.health`.
     """
-    validate_rhs(b, plan.shape)
-    _apply_cache_capacity(plan.config)
-    batch = int(b.shape[0]) if b.ndim == 3 else None
-    docc = _tuned_densify(plan)
-    return _guarded_call(
-        plan.signature(), plan.config,
-        lambda s: build_executor(s, batch=batch, densify_occupancy=docc),
-        (*plan_leaves(plan), b), "fused", lambda s: (s, batch),
-        prof=_spmm_prof(plan, b),
-    )
+    with span("lookup") as lookup:
+        validate_rhs(b, plan.shape)
+        _apply_cache_capacity(plan.config)
+        batch = int(b.shape[0]) if b.ndim == 3 else None
+        docc = _tuned_densify(plan)
+        return _guarded_call(
+            plan.signature(), plan.config,
+            lambda s: build_executor(s, batch=batch, densify_occupancy=docc),
+            (*plan_leaves(plan), b), "fused", lambda s: (s, batch),
+            lookup,
+        )
 
 
 def execute_with_delta(plan: NeutronPlan, delta, b: jax.Array) -> jax.Array:
@@ -300,22 +179,23 @@ def execute_with_delta(plan: NeutronPlan, delta, b: jax.Array) -> jax.Array:
     The sidecar joins the gather merge additively inside the same jitted
     program as the base plan's two engine paths.
     """
-    validate_rhs(b, plan.shape)
-    _apply_cache_capacity(plan.config)
-    batch = int(b.shape[0]) if b.ndim == 3 else None
-    docc = _tuned_densify(plan)
-    # dynamic dispatch rides the general payload: the structured fast lane
-    # serves static plans, and value churn (the reason a delta exists)
-    # would stale a packed payload — same demotion update_values applies
-    sig = plan_ir.general_format_sig(plan.signature())
-    return _guarded_call(
-        sig, plan.config,
-        lambda s: build_executor(s, batch=batch, delta_sig=delta.sig,
-                                 densify_occupancy=docc),
-        (*plan_leaves(plan), *delta.leaves, b),
-        "fused+delta", lambda s: (s, batch),
-        prof=_spmm_prof(plan, b),
-    )
+    with span("lookup") as lookup:
+        validate_rhs(b, plan.shape)
+        _apply_cache_capacity(plan.config)
+        batch = int(b.shape[0]) if b.ndim == 3 else None
+        docc = _tuned_densify(plan)
+        # dynamic dispatch rides the general payload: the structured fast lane
+        # serves static plans, and value churn (the reason a delta exists)
+        # would stale a packed payload — same demotion update_values applies
+        sig = plan_ir.general_format_sig(plan.signature())
+        return _guarded_call(
+            sig, plan.config,
+            lambda s: build_executor(s, batch=batch, delta_sig=delta.sig,
+                                     densify_occupancy=docc),
+            (*plan_leaves(plan), *delta.leaves, b),
+            "fused+delta", lambda s: (s, batch),
+            lookup,
+        )
 
 
 def execute_sharded(
@@ -334,47 +214,48 @@ def execute_sharded(
     axis: replicated sidecar over the column-sharded operand).  Either way
     sharded dynamic execution is one dispatch, not a post-pass.
     """
-    validate_rhs(b, splan.shape)
-    _apply_cache_capacity(splan.config)
-    batch = int(b.shape[0]) if b.ndim == 3 else None
-    if splan.shard_axis == "rhs" and b.shape[-1] % splan.n_shards:
-        raise DispatchError(
-            f"rhs-sharded plan needs N divisible by n_shards="
-            f"{splan.n_shards}; got N={b.shape[-1]} (re-prepare with "
-            f"shard_axis='rows' or pad B)"
+    with span("lookup") as lookup:
+        validate_rhs(b, splan.shape)
+        _apply_cache_capacity(splan.config)
+        batch = int(b.shape[0]) if b.ndim == 3 else None
+        if splan.shard_axis == "rhs" and b.shape[-1] % splan.n_shards:
+            raise DispatchError(
+                f"rhs-sharded plan needs N divisible by n_shards="
+                f"{splan.n_shards}; got N={b.shape[-1]} (re-prepare with "
+                f"shard_axis='rows' or pad B)"
+            )
+        if delta is not None:
+            routed = isinstance(delta, plan_ir.ShardedDeltaFringe)
+            if splan.shard_axis == "rows" and not routed:
+                raise DispatchError(
+                    "a rows-sharded plan needs its delta routed to owning "
+                    "shards (plan_ir.build_sharded_delta_fringe), got a plain "
+                    "DeltaFringe"
+                )
+            if splan.shard_axis == "rhs" and routed:
+                raise DispatchError(
+                    "an rhs-sharded plan replicates its delta; pass the plain "
+                    "DeltaFringe, not a ShardedDeltaFringe"
+                )
+        dleaves = () if delta is None else tuple(delta.leaves)
+        if splan.shard_axis == "rows":
+            args = (*splan.leaves, *dleaves, splan.assemble, b)
+        else:
+            args = (*splan.leaves, *dleaves, b)
+        docc = _tuned_densify(splan)
+        return _guarded_call(
+            splan.sig, splan.config,
+            lambda s: build_executor(
+                s, batch=batch,
+                delta_sig=None if delta is None else delta.sig,
+                mesh=splan.mesh, axis_name=splan.axis_name,
+                shard_axis=splan.shard_axis, densify_occupancy=docc,
+            ),
+            args,
+            "sharded" if delta is None else "sharded+delta",
+            lambda s: (s, splan.shard_axis, batch),
+            lookup,
         )
-    if delta is not None:
-        routed = isinstance(delta, plan_ir.ShardedDeltaFringe)
-        if splan.shard_axis == "rows" and not routed:
-            raise DispatchError(
-                "a rows-sharded plan needs its delta routed to owning "
-                "shards (plan_ir.build_sharded_delta_fringe), got a plain "
-                "DeltaFringe"
-            )
-        if splan.shard_axis == "rhs" and routed:
-            raise DispatchError(
-                "an rhs-sharded plan replicates its delta; pass the plain "
-                "DeltaFringe, not a ShardedDeltaFringe"
-            )
-    dleaves = () if delta is None else tuple(delta.leaves)
-    if splan.shard_axis == "rows":
-        args = (*splan.leaves, *dleaves, splan.assemble, b)
-    else:
-        args = (*splan.leaves, *dleaves, b)
-    docc = _tuned_densify(splan)
-    return _guarded_call(
-        splan.sig, splan.config,
-        lambda s: build_executor(
-            s, batch=batch,
-            delta_sig=None if delta is None else delta.sig,
-            mesh=splan.mesh, axis_name=splan.axis_name,
-            shard_axis=splan.shard_axis, densify_occupancy=docc,
-        ),
-        args,
-        "sharded" if delta is None else "sharded+delta",
-        lambda s: (s, splan.shard_axis, batch),
-        prof=_spmm_prof(splan, b),
-    )
 
 
 def validate_sddmm_operands(
@@ -438,68 +319,70 @@ def execute_sddmm(plan, x: jax.Array, y: jax.Array) -> jax.Array:
     """
     if isinstance(plan, ShardedPlan):
         return _execute_sddmm_sharded(plan, x, y)
-    smaps = build_sddmm_maps(plan)
-    batch = validate_sddmm_operands(x, y, plan.shape)
-    _apply_cache_capacity(plan.config)
-    if smaps.nnz == 0:
-        shape = (0,) if batch is None else (batch, 0)
-        return jnp.zeros(shape, jnp.float32)
-    vmem_budget = plan.config.fringe_vmem_budget
-    if getattr(plan.config, "autotune", False) and plan.config.impl != "xla":
-        cm = tuner.resolve_cost_model(
-            "sddmm", int(plan.shape[0]), int(plan.shape[1]), smaps.nnz,
-            plan.config,
+    with span("lookup") as lookup:
+        smaps = build_sddmm_maps(plan)
+        batch = validate_sddmm_operands(x, y, plan.shape)
+        _apply_cache_capacity(plan.config)
+        if smaps.nnz == 0:
+            shape = (0,) if batch is None else (batch, 0)
+            return jnp.zeros(shape, jnp.float32)
+        vmem_budget = plan.config.fringe_vmem_budget
+        cfg = plan.config
+        if getattr(cfg, "autotune", False) and cfg.impl != "xla":
+            cm = tuner.resolve_cost_model(
+                "sddmm", int(plan.shape[0]), int(plan.shape[1]), smaps.nnz,
+                plan.config,
+            )
+            tier = cm.select_sddmm_tier(
+                int(x.shape[-1]), int(plan.shape[0]), int(plan.shape[1]),
+                vmem_budget=vmem_budget,
+            )
+            if tier == "xla":
+                # measured demotion, encoded as a zero budget in the op tag so
+                # the fused body's tier="auto" resolves to the XLA gather; the
+                # table can demote past the analytic budget but never promote
+                vmem_budget = 0
+        sig = plan_ir.tag_op(
+            plan.signature(), "sddmm", smaps.nnz, smaps.nnz_f, vmem_budget,
         )
-        tier = cm.select_sddmm_tier(
-            int(x.shape[-1]), int(plan.shape[0]), int(plan.shape[1]),
-            vmem_budget=vmem_budget,
+        return _guarded_call(
+            sig, plan.config,
+            lambda s: build_executor(s, batch=batch),
+            (*sddmm_body_leaves(plan, smaps), x, y),
+            "sddmm", lambda s: (s, batch),
+            lookup,
         )
-        if tier == "xla":
-            # measured demotion, encoded as a zero budget in the op tag so
-            # the fused body's tier="auto" resolves to the XLA gather; the
-            # table can demote past the analytic budget but never promote
-            vmem_budget = 0
-    sig = plan_ir.tag_op(
-        plan.signature(), "sddmm", smaps.nnz, smaps.nnz_f, vmem_budget,
-    )
-    return _guarded_call(
-        sig, plan.config,
-        lambda s: build_executor(s, batch=batch),
-        (*sddmm_body_leaves(plan, smaps), x, y),
-        "sddmm", lambda s: (s, batch),
-        prof=_sddmm_prof(plan.config, smaps.nnz, smaps.nnz_f,
-                         int(x.shape[-1]), batch),
-    )
 
 
 def _execute_sddmm_sharded(
     splan: ShardedPlan, x: jax.Array, y: jax.Array
 ) -> jax.Array:
-    maps = splan.update_maps
-    if maps is None:
-        raise PlanBuildError(
-            "sddmm on a sharded plan needs its global COO mirror "
-            "(ShardedUpdateMaps); this plan lost it — re-prepare from COO"
+    with span("lookup") as lookup:
+        maps = splan.update_maps
+        if maps is None:
+            raise PlanBuildError(
+                "sddmm on a sharded plan needs its global COO mirror "
+                "(ShardedUpdateMaps); this plan lost it — re-prepare from "
+                "COO"
+            )
+        batch = validate_sddmm_operands(x, y, splan.shape)
+        _apply_cache_capacity(splan.config)
+        if maps.nnz == 0:
+            shape = (0,) if batch is None else (batch, 0)
+            return jnp.zeros(shape, jnp.float32)
+        flat = getattr(maps, "_sddmm_flat", None)
+        if flat is None:  # structure-only device mirror, cached on the maps
+            flat = (jnp.asarray(maps.rows, jnp.int32),
+                    jnp.asarray(maps.cols, jnp.int32))
+            maps._sddmm_flat = flat
+        cfg = splan.config
+        sig = ("sddmm_flat", cfg.impl, maps.nnz, cfg.fringe_chunk)
+        return _guarded_call(
+            sig, cfg,
+            lambda s: build_executor(s, batch=batch),
+            (*flat, x, y), "sddmm", lambda s: (s, batch),
+            lookup,
         )
-    batch = validate_sddmm_operands(x, y, splan.shape)
-    _apply_cache_capacity(splan.config)
-    if maps.nnz == 0:
-        shape = (0,) if batch is None else (batch, 0)
-        return jnp.zeros(shape, jnp.float32)
-    flat = getattr(maps, "_sddmm_flat", None)
-    if flat is None:  # structure-only device mirror, cached on the maps
-        flat = (jnp.asarray(maps.rows, jnp.int32),
-                jnp.asarray(maps.cols, jnp.int32))
-        maps._sddmm_flat = flat
-    cfg = splan.config
-    sig = ("sddmm_flat", cfg.impl, maps.nnz, cfg.fringe_chunk)
-    return _guarded_call(
-        sig, cfg,
-        lambda s: build_executor(s, batch=batch),
-        (*flat, x, y), "sddmm", lambda s: (s, batch),
-        # flat global gather form: every nonzero rides the vector path
-        prof=_sddmm_prof(cfg, maps.nnz, maps.nnz, int(x.shape[-1]), batch),
-    )
 
 
 def execute_spspmm(a_plan, b_plan) -> Tuple:
@@ -576,15 +459,16 @@ def execute_spspmm(a_plan, b_plan) -> Tuple:
 
     # --- numeric phase (one jitted dispatch) ------------------------------
     sig = ("spspmm", n_exp, nnz_c)
-    vals = _guarded_call(
-        sig, a_plan.config,
-        lambda s: build_executor(s),
-        (jnp.asarray(ae, jnp.int32), jnp.asarray(be, jnp.int32),
-         jnp.asarray(ce, jnp.int32), jnp.asarray(ma.vals),
-         jnp.asarray(mb.vals)),
-        "spspmm", lambda s: s,
-        prof=_spspmm_prof(a_plan.config, n_exp, nnz_c),
-    )
+    with span("lookup") as lookup:
+        vals = _guarded_call(
+            sig, a_plan.config,
+            lambda s: build_executor(s),
+            (jnp.asarray(ae, jnp.int32), jnp.asarray(be, jnp.int32),
+             jnp.asarray(ce, jnp.int32), jnp.asarray(ma.vals),
+             jnp.asarray(mb.vals)),
+            "spspmm", lambda s: s,
+            lookup,
+        )
     return c_keys // n, c_keys % n, vals, (m, n)
 
 

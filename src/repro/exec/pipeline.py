@@ -20,6 +20,15 @@ dispatch like everything else.
 
 All executors live in one bounded LRU (``exec.cache.EXECUTOR_CACHE``) keyed
 by (signature, delta signature, batch, mesh, shard axis).
+
+Every body stage runs under one of the ``jax.named_scope`` names in
+:data:`SCOPES`: ``b_prep`` (the operand permutation, padding and
+relayout), ``matrix_path`` (the tile stream), ``fringe_path`` (the
+per-nonzero gather kernels) and ``merge`` (the gathers of both paths into
+the output's order, and their sum).  A scope is compile-time metadata,
+the ``op_name`` of each HLO operation: it costs nothing at run time and
+keys no cache, and on a profiler trace it names the stage each device
+operation belongs to.
 """
 from __future__ import annotations
 
@@ -43,6 +52,10 @@ from ..kernels import ops
 from ..obs import REGISTRY
 from ..robust.faults import HARNESS
 from .cache import EXECUTOR_CACHE, record_fused_trace, record_sharded_trace
+
+#: The fused bodies' stage scopes, in dataflow order.
+SCOPES = ("b_prep", "matrix_path", "fringe_path", "merge")
+B_PREP, MATRIX_PATH, FRINGE_PATH, MERGE = SCOPES
 
 _BUILDS = REGISTRY.counter(
     "exec_executor_builds_total",
@@ -77,9 +90,10 @@ def _fused_body(sig: Tuple, densify_occupancy: Optional[float] = None):
         if impl != "xla":  # pallas tiers lower here, at trace time
             HARNESS.fire("pallas_lowering", context=sig)
         n = b.shape[1]
-        bp = permute_pad_b(b, col_perm, reorder_cols, bk, bn)
+        with jax.named_scope(B_PREP):
+            bp = permute_pad_b(b, col_perm, reorder_cols, bk, bn)
 
-        c = None
+        packed_m = packed_v = None
         if has_core:
             # structured fast lane: the signature-carried format selects
             # which payload the matrix stage consumes (the general flat
@@ -87,40 +101,47 @@ def _fused_body(sig: Tuple, densify_occupancy: Optional[float] = None):
             # same leaves).  Same degrade-to-XLA health gating: an impl
             # demotion via xla_fallback_sig keeps the format and routes it
             # to the structured XLA reference form.
-            if matrix_format == "nm":
-                n_pat, m_pat = format_params
-                packed_m = ops.nm_stream_spmm(
-                    step_window, step_col, nm_values, nm_codes, bp,
-                    num_windows=num_windows, bm=bm, bk=bk, bn=bn,
-                    n_pat=n_pat, m_pat=m_pat, impl=impl,
-                )[:, :n]
-            elif matrix_format == "bitmap":
-                _n_words, row_cap = format_params
-                packed_m = ops.bitmap_stream_spmm(
-                    step_window, step_col, bitmap_words, bitmap_values, bp,
-                    num_windows=num_windows, bm=bm, bk=bk, bn=bn,
-                    row_cap=row_cap, impl=impl,
-                )[:, :n]
-            else:
-                packed_m = ops.block_stream_spmm(
-                    step_window, step_col, flat_values, bp,
-                    num_windows=num_windows, bm=bm, bk=bk, bn=bn, impl=impl,
-                    assume_unique=True,  # prepare() emits unique pairs
-                    densify_occupancy=densify_occupancy,
-                )[:, :n]
-            c = gather_rows(packed_m, gsrc_m)
+            with jax.named_scope(MATRIX_PATH):
+                if matrix_format == "nm":
+                    n_pat, m_pat = format_params
+                    packed_m = ops.nm_stream_spmm(
+                        step_window, step_col, nm_values, nm_codes, bp,
+                        num_windows=num_windows, bm=bm, bk=bk, bn=bn,
+                        n_pat=n_pat, m_pat=m_pat, impl=impl,
+                    )[:, :n]
+                elif matrix_format == "bitmap":
+                    _n_words, row_cap = format_params
+                    packed_m = ops.bitmap_stream_spmm(
+                        step_window, step_col, bitmap_words, bitmap_values,
+                        bp, num_windows=num_windows, bm=bm, bk=bk, bn=bn,
+                        row_cap=row_cap, impl=impl,
+                    )[:, :n]
+                else:
+                    packed_m = ops.block_stream_spmm(
+                        step_window, step_col, flat_values, bp,
+                        num_windows=num_windows, bm=bm, bk=bk, bn=bn,
+                        impl=impl,
+                        assume_unique=True,  # prepare() emits unique pairs
+                        densify_occupancy=densify_occupancy,
+                    )[:, :n]
         if has_fringe:
-            packed_v = ops.fringe_spmm(
-                fringe_rows, fringe_cols, fringe_vals, bp,
-                num_rows=n_fringe_rows, bn=bn, impl=impl, chunk=fringe_chunk,
-                tier=fringe_tier, bk=fringe_bk,
-                kb_chunk=kb_chunk, kb_rows=kb_rows,
-                kb_cols=kb_cols, kb_vals=kb_vals,
-            )[:, :n]
-            cv = gather_rows(packed_v, gsrc_v)
-            c = cv if c is None else c + cv
-        if c is None:  # empty matrix
-            c = jnp.zeros((m, n), jnp.float32)
+            with jax.named_scope(FRINGE_PATH):
+                packed_v = ops.fringe_spmm(
+                    fringe_rows, fringe_cols, fringe_vals, bp,
+                    num_rows=n_fringe_rows, bn=bn, impl=impl,
+                    chunk=fringe_chunk, tier=fringe_tier, bk=fringe_bk,
+                    kb_chunk=kb_chunk, kb_rows=kb_rows,
+                    kb_cols=kb_cols, kb_vals=kb_vals,
+                )[:, :n]
+        with jax.named_scope(MERGE):
+            c = None
+            if packed_m is not None:
+                c = gather_rows(packed_m, gsrc_m)
+            if packed_v is not None:
+                cv = gather_rows(packed_v, gsrc_v)
+                c = cv if c is None else c + cv
+            if c is None:  # empty matrix
+                c = jnp.zeros((m, n), jnp.float32)
         return c
 
     return _run
@@ -152,40 +173,49 @@ def _sddmm_body(sig: Tuple):
         record_fused_trace(sig)
         if impl != "xla":  # pallas tiers lower here, at trace time
             HARNESS.fire("pallas_lowering", context=sig)
-        yt = jnp.swapaxes(y, 0, 1)  # (K, D): both gathers address rows
+        with jax.named_scope(B_PREP):
+            yt = jnp.swapaxes(y, 0, 1)  # (K, D): both gathers address rows
         if impl == "xla" or not (has_core or has_fringe):
             # reference gather over every nonzero — also the complete
             # degrade target xla_fallback_sig demotes pallas failures to
-            return ops.sddmm_gather(
-                g_rows, g_cols, x, yt, impl="xla", chunk=fringe_chunk,
-            )
-        core_vals = None
+            with jax.named_scope(FRINGE_PATH):
+                return ops.sddmm_gather(
+                    g_rows, g_cols, x, yt, impl="xla", chunk=fringe_chunk,
+                )
+        tiles = fv = None
         if has_core:
             # matrix path: window-gathered X rows x column-permuted Y panel
-            xp = jnp.where(
-                (core_row_map >= 0)[:, None],
-                x[jnp.clip(core_row_map, 0, x.shape[0] - 1)], 0.0,
-            )
-            yp = y[:, col_perm] if reorder_cols else y
-            k_pad = ((k + bk - 1) // bk) * bk
-            if k_pad != k:
-                yp = jnp.pad(yp, ((0, 0), (0, k_pad - k)))
-            tiles = ops.sddmm_block_stream(
-                step_window, step_col, xp, yp, bm=bm, bk=bk, impl=impl,
-            )
-            core_vals = tiles.reshape(-1)[jnp.clip(core_lin, 0)]
-        fringe_vals = None
+            with jax.named_scope(B_PREP):
+                xp = jnp.where(
+                    (core_row_map >= 0)[:, None],
+                    x[jnp.clip(core_row_map, 0, x.shape[0] - 1)], 0.0,
+                )
+                yp = y[:, col_perm] if reorder_cols else y
+                k_pad = ((k + bk - 1) // bk) * bk
+                if k_pad != k:
+                    yp = jnp.pad(yp, ((0, 0), (0, k_pad - k)))
+            with jax.named_scope(MATRIX_PATH):
+                tiles = ops.sddmm_block_stream(
+                    step_window, step_col, xp, yp, bm=bm, bk=bk, impl=impl,
+                )
         if has_fringe:
-            fv = ops.sddmm_gather(
-                f_rows, f_cols, x, yt, impl=impl, chunk=fringe_chunk,
-                vmem_budget=vmem_budget,
-            )
-            fringe_vals = fv[jnp.clip(f_idx, 0)]
-        if core_vals is None:
-            return fringe_vals
-        if fringe_vals is None:
-            return core_vals
-        return jnp.where(core_lin >= 0, core_vals, fringe_vals)
+            with jax.named_scope(FRINGE_PATH):
+                fv = ops.sddmm_gather(
+                    f_rows, f_cols, x, yt, impl=impl, chunk=fringe_chunk,
+                    vmem_budget=vmem_budget,
+                )
+        # merge: both paths' values back into the plan's COO input order
+        with jax.named_scope(MERGE):
+            core_vals = fringe_vals = None
+            if tiles is not None:
+                core_vals = tiles.reshape(-1)[jnp.clip(core_lin, 0)]
+            if fv is not None:
+                fringe_vals = fv[jnp.clip(f_idx, 0)]
+            if core_vals is None:
+                return fringe_vals
+            if fringe_vals is None:
+                return core_vals
+            return jnp.where(core_lin >= 0, core_vals, fringe_vals)
 
     return _run
 
@@ -204,8 +234,11 @@ def _sddmm_flat_body(sig: Tuple):
 
     def _run(g_rows, g_cols, x, y):
         record_fused_trace(sig)
-        yt = jnp.swapaxes(y, 0, 1)
-        return ops.sddmm_gather(g_rows, g_cols, x, yt, impl=impl, chunk=chunk)
+        with jax.named_scope(B_PREP):
+            yt = jnp.swapaxes(y, 0, 1)
+        with jax.named_scope(FRINGE_PATH):
+            return ops.sddmm_gather(g_rows, g_cols, x, yt, impl=impl,
+                                    chunk=chunk)
 
     return _run
 
@@ -245,14 +278,17 @@ def _delta_contrib_body(m: int, bk_cfg: int, bn: int, impl,
     def contrib(d_rows, d_cols, d_vals, d_gsrc, kbc, kbr, kbcol, kbv,
                 col_perm, b):
         n = b.shape[1]
-        bp = permute_pad_b(b, col_perm, reorder_cols, bk_cfg, bn)
-        packed = ops.delta_fringe_spmm(
-            d_rows, d_cols, d_vals, bp,
-            num_rows=num_rows, bn=bn, impl=impl, chunk=fringe_chunk,
-            tier=tier, bk=dbk,
-            kb_chunk=kbc, kb_rows=kbr, kb_cols=kbcol, kb_vals=kbv,
-        )[:, :n]
-        return gather_rows(packed, d_gsrc)
+        with jax.named_scope(B_PREP):
+            bp = permute_pad_b(b, col_perm, reorder_cols, bk_cfg, bn)
+        with jax.named_scope(FRINGE_PATH):
+            packed = ops.delta_fringe_spmm(
+                d_rows, d_cols, d_vals, bp,
+                num_rows=num_rows, bn=bn, impl=impl, chunk=fringe_chunk,
+                tier=tier, bk=dbk,
+                kb_chunk=kbc, kb_rows=kbr, kb_cols=kbcol, kb_vals=kbv,
+            )[:, :n]
+        with jax.named_scope(MERGE):
+            return gather_rows(packed, d_gsrc)
 
     return contrib
 
@@ -293,7 +329,10 @@ def _flat_body(sig: Tuple, dsig: Optional[Tuple],
         leaves = args[:N_PLAN_LEAVES]
         dleaves = args[N_PLAN_LEAVES:N_PLAN_LEAVES + N_DELTA_LEAVES]
         b = args[-1]
-        return run(*leaves, b) + contrib(*dleaves, leaves[LEAF_COL_PERM], b)
+        c = run(*leaves, b)
+        d = contrib(*dleaves, leaves[LEAF_COL_PERM], b)
+        with jax.named_scope(MERGE):
+            return c + d
 
     return body, N_PLAN_LEAVES + N_DELTA_LEAVES, 1
 
@@ -362,7 +401,8 @@ def _build(sig: Tuple, batch: Optional[int], dsig: Optional[Tuple],
             record_sharded_trace((sig, shard_axis, batch, dsig))
             *leaves, assemble, b = args
             flat = sm(*leaves, b)  # (..., n_shards * rows_per_shard, N)
-            return jnp.take(flat, assemble, axis=-2)
+            with jax.named_scope(MERGE):
+                return jnp.take(flat, assemble, axis=-2)
 
         return _exec
 

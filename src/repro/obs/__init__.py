@@ -1,4 +1,4 @@
-"""repro.obs — unified telemetry: metrics, traces, roofline attribution.
+"""repro.obs — unified telemetry: metrics, request traces, phase spans.
 
 Bottom-of-graph layer (beside ``errors``): imports nothing from the rest
 of ``repro``, so every layer above — including ``robust`` — may publish
@@ -8,15 +8,18 @@ into it.  Three surfaces:
   island in the codebase (health table, fault seams, tuner, executor
   cache, serving stats, test hooks) records here.
 - :data:`TRACES` — ring buffer of completed per-request traces from the
-  serving layer and the ``repro.sparse`` facade.
-- :data:`PROFILER` — per-dispatch measurements (telemetry-enabled plans
-  only) that :func:`snapshot` aggregates into the matrix-path vs
-  fringe-path roofline attribution.
+  serving layer and the ``repro.sparse`` facade (``SpmmConfig.telemetry``
+  plans only).
+- :class:`span` — the program's host phase spans (``repro.call``,
+  ``repro.lookup``, ``repro.launch``, ``repro.flush``, ...), written on
+  the profiler's clock whenever ``jax.profiler`` traces, with their recent
+  host durations in :data:`SPAN_TIMES`.  Device time per stage of the
+  fused body is on the same trace, under the ``jax.named_scope`` names of
+  ``exec.pipeline.SCOPES``.
 
 ``snapshot()`` returns the whole state as JSON-serializable dicts;
-``prometheus_text()`` emits the Prometheus text exposition (registry
-metrics plus roofline gauges) that ``metrics.parse_prometheus_text``
-round-trips.
+``prometheus_text()`` emits the registry's Prometheus text exposition,
+which ``metrics.parse_prometheus_text`` round-trips.
 """
 from __future__ import annotations
 
@@ -33,9 +36,7 @@ from .metrics import (
     instance_label,
     parse_prometheus_text,
 )
-from .profile import PATHS, DispatchProfiler, DispatchRecord, PROFILER
-from .report import format_report, roofline_attribution, roofline_prometheus
-from .trace import Span, Trace, TraceStore, TRACES
+from .trace import SPAN_TIMES, Span, SpanTimes, Trace, TraceStore, TRACES, span
 
 __all__ = [
     "Counter",
@@ -47,52 +48,39 @@ __all__ = [
     "get_registry",
     "instance_label",
     "parse_prometheus_text",
-    "PATHS",
-    "DispatchProfiler",
-    "DispatchRecord",
-    "PROFILER",
-    "format_report",
-    "roofline_attribution",
-    "roofline_prometheus",
+    "SPAN_TIMES",
     "Span",
+    "SpanTimes",
     "Trace",
     "TraceStore",
     "TRACES",
+    "span",
     "snapshot",
     "prometheus_text",
-    "roofline",
     "reset_for_tests",
 ]
 
 
-def roofline(*, include_traced: bool = False) -> Dict[str, Any]:
-    """Matrix-path vs fringe-path attribution over the profiler ring."""
-    return roofline_attribution(PROFILER.records(),
-                                include_traced=include_traced)
-
-
-def snapshot(*, trace_limit: Optional[int] = 64,
-             include_traced: bool = False) -> Dict[str, Any]:
+def snapshot(*, trace_limit: Optional[int] = 64) -> Dict[str, Any]:
     """One JSON-serializable dict of all telemetry state."""
     return {
         "metrics": REGISTRY.snapshot(),
         "traces": TRACES.snapshot(trace_limit),
-        "roofline": roofline(include_traced=include_traced),
+        "spans": SPAN_TIMES.snapshot(),
     }
 
 
-def prometheus_text(*, include_traced: bool = False) -> str:
-    """Prometheus text exposition: registry metrics + roofline gauges."""
-    return (REGISTRY.to_prometheus()
-            + roofline_prometheus(roofline(include_traced=include_traced)))
+def prometheus_text() -> str:
+    """Prometheus text exposition of the registry's metrics."""
+    return REGISTRY.to_prometheus()
 
 
 def reset_for_tests() -> None:
-    """Zero all metric series and drop traces/profile records.
+    """Zero all metric series and drop traces and span durations.
 
     Metric *objects* (and their registrations) survive — modules register
     at import time; only values reset.
     """
     REGISTRY.reset_values()
     TRACES.reset()
-    PROFILER.reset()
+    SPAN_TIMES.reset()

@@ -331,7 +331,7 @@ def _escape_label(s: str) -> str:
 
 
 def format_sample(name: str, labels: Dict[str, Any], value: Any) -> str:
-    """One Prometheus text sample line (shared with the roofline export)."""
+    """One Prometheus text sample line."""
     if labels:
         body = ",".join(
             f'{k}="{_escape_label(str(v))}"' for k, v in sorted(

@@ -1,4 +1,4 @@
-"""Per-request tracing: spans in a bounded ring, deterministic clock.
+"""Per-request tracing in a bounded ring, and the program's phase spans.
 
 A :class:`Trace` is one request's life (a serving ticket, a facade
 operator call); a :class:`Span` is one named phase inside it — the
@@ -13,6 +13,14 @@ convention); callers that already own an injectable clock — the serving
 layer's ``self._clock`` — pass explicit timestamps instead.  Tests pin
 span structure *exactly* by injecting a deterministic counter clock.
 
+Program phase spans (:class:`span`) are the other surface: ``with
+span("lookup"):`` opens the host span ``repro.lookup`` as a
+``jax.profiler.TraceAnnotation``, so under a profiler it lands on the
+device trace's clock beside the operations it launched, and with no
+profiler running it costs about a microsecond.  Each span's host duration
+also goes to :data:`SPAN_TIMES`, a bounded ring per span name, so the
+recent phase times can be read without a profiler.
+
 Host-side only: nothing here touches device state.
 """
 from __future__ import annotations
@@ -23,7 +31,13 @@ from collections import deque
 from contextlib import contextmanager
 from typing import Any, Dict, Iterator, List, Optional
 
+from jax.profiler import TraceAnnotation
+
 DEFAULT_TRACE_CAPACITY = 512
+DEFAULT_SPAN_CAPACITY = 8192
+
+#: Prefix of every program phase span's name on the profiler's trace.
+SPAN_PREFIX = "repro."
 
 
 class Span:
@@ -160,3 +174,81 @@ class TraceStore:
 
 #: Process-wide trace ring used by the serving layer and the facade.
 TRACES = TraceStore()
+
+
+class SpanTimes:
+    """Host durations (ns) of the most recent ``capacity`` spans of each
+    name; a deque append per span, so it is always on."""
+
+    def __init__(self, capacity: int = DEFAULT_SPAN_CAPACITY):
+        self._capacity = int(capacity)
+        self._rings: Dict[str, "deque[int]"] = {}
+        self._lock = threading.Lock()
+
+    def record(self, name: str, ns: int) -> None:
+        ring = self._rings.get(name)
+        if ring is None:
+            with self._lock:
+                ring = self._rings.setdefault(
+                    name, deque(maxlen=self._capacity))
+        ring.append(ns)
+
+    def durations_ns(self, name: str) -> List[int]:
+        """The recorded durations of ``name``, oldest first."""
+        ring = self._rings.get(name)
+        return list(ring) if ring is not None else []
+
+    def snapshot(self) -> Dict[str, Dict[str, float]]:
+        """Per span name: count held, median and maximum in us."""
+        out = {}
+        for name in sorted(self._rings):
+            ns = sorted(self.durations_ns(name))
+            if ns:
+                out[name] = {"count": len(ns),
+                             "p50_us": ns[len(ns) // 2] * 1e-3,
+                             "max_us": ns[-1] * 1e-3}
+        return out
+
+    def reset(self) -> None:
+        with self._lock:
+            self._rings.clear()
+
+
+#: Process-wide phase-span durations, filled by :class:`span`.
+SPAN_TIMES = SpanTimes()
+
+
+class span:
+    """``with span("launch"):`` -- the program's host phase span.
+
+    Opens ``repro.<name>`` on the profiler's trace and records its host
+    duration in :data:`SPAN_TIMES`.  :meth:`close` ends it early (the
+    dispatch path closes ``lookup`` right before it launches); the
+    ``with`` block's own exit then does nothing.
+    """
+
+    __slots__ = ("name", "_annotation", "_t0")
+
+    def __init__(self, name: str):
+        self.name = name
+        self._annotation = TraceAnnotation(SPAN_PREFIX + name)
+        self._t0 = 0
+
+    def __enter__(self) -> "span":
+        self._annotation.__enter__()
+        self._t0 = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.close()
+
+    @property
+    def closed(self) -> bool:
+        return self._annotation is None
+
+    def close(self) -> None:
+        if self._annotation is None:
+            return
+        SPAN_TIMES.record(self.name, time.perf_counter_ns() - self._t0)
+        self._annotation.__exit__(None, None, None)
+        self._annotation = None

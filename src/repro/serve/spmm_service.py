@@ -58,7 +58,7 @@ from ..errors import (
 )
 from ..exec.health import HEALTH
 from ..kernels.ops import pow2_at_least
-from ..obs import REGISTRY, TRACES, instance_label
+from ..obs import REGISTRY, TRACES, instance_label, span
 from ..robust.faults import HARNESS
 
 #: Admission policies for a full per-matrix queue (``max_queue`` set).
@@ -727,6 +727,10 @@ class SpmmService:
         result-less."""
         if name is not None and name not in self._queues:
             raise KeyError(f"no matrix registered under {name!r}")
+        with span("flush"):
+            return self._flush(name)
+
+    def _flush(self, name: Optional[str]) -> int:
         if self.async_compaction:
             self.poll_compactions()  # swap finished folds in between drains
         if self._background_tune:
@@ -740,28 +744,26 @@ class SpmmService:
             plan = self._plans[qname]
             # expired requests complete with DeadlineExceeded up front —
             # they never join a batch, and the batch never waits for them
-            self._expire_queue(qname)
+            with span("expire"):
+                self._expire_queue(qname)
             while queue:
                 t_asm0 = self._now_us() if self._trace_enabled else 0.0
-                # FIFO head's shape defines this round's group
-                shape = tuple(queue[0][1].shape)
-                group = [item for item in queue
-                         if tuple(item[1].shape) == shape][: self.max_batch]
-                bucket = _bucket(len(group), self.max_batch)
-                panels = [b for _, b, _ in group]
-                if bucket > len(panels):  # pad to the bucket with zeros so
-                    pad = jnp.zeros_like(panels[0])  # one trace per bucket
-                    panels += [pad] * (bucket - len(panels))
-                stacked = jnp.stack(panels)
+                with span("assemble"):
+                    # FIFO head's shape defines this round's group
+                    shape = tuple(queue[0][1].shape)
+                    group = [item for item in queue
+                             if tuple(item[1].shape) == shape]
+                    group = group[: self.max_batch]
+                    bucket = _bucket(len(group), self.max_batch)
+                    panels = [b for _, b, _ in group]
+                    if bucket > len(panels):
+                        # pad to the bucket with zeros: one trace per bucket
+                        pad = jnp.zeros_like(panels[0])
+                        panels += [pad] * (bucket - len(panels))
+                    stacked = jnp.stack(panels)
                 t_disp0 = self._now_us() if self._trace_enabled else 0.0
                 out = self._execute(qname, plan, stacked)
-                if self._trace_enabled:
-                    t_disp1 = self._now_us()
-                    # the one telemetry-visible sync: waiting on the same
-                    # dispatch (no extra device work) so the span split
-                    # between enqueue and compute is real
-                    jax.block_until_ready(out)
-                    t_block = self._now_us()
+                t_disp1 = self._now_us() if self._trace_enabled else 0.0
                 # dispatch succeeded: now dequeue and record
                 dispatched = {ticket for ticket, _, _ in group}
                 queue[:] = [it for it in queue if it[0] not in dispatched]
@@ -780,8 +782,6 @@ class SpmmService:
                     TRACES.add_span(tr, "batch_assembly", t_asm0, t_disp0,
                                     batch=len(group), bucket=bucket)
                     TRACES.add_span(tr, "dispatch", t_disp0, t_disp1)
-                    TRACES.add_span(tr, "block_until_ready", t_disp1,
-                                    t_block)
                 done += len(group)
         self.stats.flushes += 1
         return done
@@ -795,6 +795,10 @@ class SpmmService:
         once, like a result).  Otherwise raises a KeyError that says *why*
         the ticket has no result: never issued, still queued (flush
         first), or already fetched."""
+        with span("fetch"):
+            return self._fetch(ticket)
+
+    def _fetch(self, ticket: int) -> jax.Array:
         if ticket in self._results:
             t0 = self._now_us() if self._trace_enabled else 0.0
             out = self._results.pop(ticket)

@@ -49,7 +49,7 @@ from .dynamic import DynamicPlan
 from .dynamic import update_values as _dynamic_update_values
 from .errors import DeadlineExceeded, PlanBuildError
 from .exec import api as _exec
-from .obs import TRACES
+from .obs import TRACES, span
 
 __all__ = [
     "SparseMatrix", "from_coo", "from_plan",
@@ -66,12 +66,17 @@ def _telemetry_on(plan: PlanLike) -> bool:
 
 
 def _traced_call(name: str, plan: PlanLike, attrs, fn):
-    """Run ``fn()``; when the plan opts in, record an obs trace around it.
+    """Run ``fn()`` under the ``repro.call`` phase span; when the plan
+    opts in, also record an obs trace around it.
 
     The trace wraps the dispatch *and* the deadline await in a single
-    ``dispatch`` span — host-side bookkeeping only, so the off path is
-    exactly the bare call.
+    ``dispatch`` span — host-side bookkeeping only.
     """
+    with span("call"):
+        return _request_traced(name, plan, attrs, fn)
+
+
+def _request_traced(name: str, plan: PlanLike, attrs, fn):
     if not _telemetry_on(plan):
         return fn()
     tr = TRACES.begin(f"facade:{name}", **attrs)

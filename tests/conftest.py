@@ -19,6 +19,20 @@ def rng():
     return np.random.RandomState(0)
 
 
+@pytest.fixture(autouse=True, scope="module")
+def _degraded_state_stays_in_its_module():
+    """Health failures and ``:degraded`` dispatch series a module leaves
+    are cleared when it ends: later modules in the same worker process
+    (the chip benchmark's CPU tests among them, which refuse a run once
+    the process has any) read the process-wide health table and registry."""
+    yield
+    from repro.exec.health import HEALTH
+    from repro.obs import REGISTRY
+
+    HEALTH.reset()
+    REGISTRY.reset_values(["exec_dispatches_total"])
+
+
 @pytest.fixture(scope="session")
 def forced_mesh_run():
     """Run a python script in a subprocess with a forced host device count.

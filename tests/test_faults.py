@@ -157,6 +157,21 @@ def test_pallas_lowering_failure_degrades(rng):
     assert HEALTH.is_degraded(plan.signature())
 
 
+def test_failed_launch_and_fallback_each_time_their_spans(rng):
+    """A failed accelerated launch and its XLA fallback record two
+    ``repro.lookup`` and two ``repro.launch`` spans; nothing leaks into the
+    next call's count."""
+    from repro.obs import SPAN_TIMES
+
+    _, plan = _plan(rng, 71, 45, impl="pallas_interpret")
+    b = jnp.asarray(rng.randn(45, 8).astype(np.float32))
+    SPAN_TIMES.reset()
+    with armed("pallas_lowering", times=1):
+        spmm.execute(plan, b)
+    assert len(SPAN_TIMES.durations_ns("lookup")) == 2
+    assert len(SPAN_TIMES.durations_ns("launch")) == 2
+
+
 def test_transient_failure_recovers_inside_retry_window(rng):
     _, plan = _plan(rng, 60, 44, impl="pallas_interpret")
     b = jnp.asarray(np.random.RandomState(7).randn(44, 8).astype(np.float32))

@@ -2,23 +2,23 @@
 
 Covers the metrics registry (types, labels, cardinality caps, thread
 safety, Prometheus round-trip), the trace store (deterministic clock,
-ring bound), the dispatch profiler ring, and the roofline attribution
-math — all pure host-side, no jax.
+ring bound) and the program's phase spans (host durations, early close,
+ring bound) — all host-side, no device work.
 """
 import threading
 
 import pytest
 
+import repro.obs as obs
 from repro.obs import (
-    DispatchProfiler,
-    DispatchRecord,
+    SPAN_TIMES,
     MetricsRegistry,
+    SpanTimes,
     TraceStore,
     format_sample,
     instance_label,
     parse_prometheus_text,
-    roofline_attribution,
-    roofline_prometheus,
+    span,
 )
 from repro.obs.metrics import OVERFLOW_LABEL
 
@@ -217,84 +217,46 @@ def test_trace_ring_bounded():
 
 
 # ---------------------------------------------------------------------------
-# profiler + roofline attribution
+# program phase spans
 # ---------------------------------------------------------------------------
 
-PEAKS = {"flops_per_s": 1e9, "bytes_per_s": 1e9}
+
+def test_span_records_host_duration():
+    SPAN_TIMES.reset()
+    with span("test_phase") as sp:
+        assert not sp.closed
+        sum(range(10_000))
+    assert sp.closed
+    (ns,) = SPAN_TIMES.durations_ns("test_phase")
+    assert ns > 0
+    assert SPAN_TIMES.durations_ns("never_opened") == []
+    snap = obs.snapshot()["spans"]["test_phase"]
+    assert snap["count"] == 1
+    assert snap["p50_us"] == snap["max_us"] == pytest.approx(ns * 1e-3)
 
 
-def _rec(op="spmm", tier="pallas", sig="aaaa", measured_us=30.0,
-         traced=False, matrix=(10_000.0, 100.0), fringe=(100.0, 10_000.0)):
-    return DispatchRecord(
-        op=op, tier=tier, sig_key=sig, kind=op, measured_us=measured_us,
-        traced=traced, batch=None,
-        terms={"matrix": {"flops": matrix[0], "bytes": matrix[1]},
-               "fringe": {"flops": fringe[0], "bytes": fringe[1]}},
-        peaks=PEAKS,
-    )
+def test_span_close_ends_it_once():
+    SPAN_TIMES.reset()
+    with span("early") as sp:
+        sp.close()
+        sp.close()
+        with span("after"):
+            pass
+    assert len(SPAN_TIMES.durations_ns("early")) == 1
+    assert len(SPAN_TIMES.durations_ns("after")) == 1
+    # an exception still ends the span, and is not swallowed
+    with pytest.raises(ValueError):
+        with span("raising"):
+            raise ValueError("boom")
+    assert len(SPAN_TIMES.durations_ns("raising")) == 1
 
 
-def test_profiler_ring():
-    prof = DispatchProfiler(capacity=3)
-    for i in range(5):
-        prof.record(op="spmm", tier="xla", sig_key=f"{i}", kind="spmm",
-                    measured_us=1.0, traced=False, batch=None, terms={},
-                    peaks=PEAKS)
-    recs = prof.records()
-    assert len(recs) == 3
-    assert [r.sig_key for r in recs] == ["2", "3", "4"]
-    prof.reset()
-    assert len(prof) == 0
-
-
-def test_roofline_attribution_math():
-    # matrix path: compute-bound at 10us; fringe path: memory-bound at 10us
-    attr = roofline_attribution([_rec(measured_us=40.0)])
-    (row,) = attr["rows"]
-    assert row["calls"] == 1
-    assert row["measured_us"] == pytest.approx(40.0)
-    mat, fr = row["paths"]["matrix"], row["paths"]["fringe"]
-    assert mat["bound_us"] == pytest.approx(10.0)
-    assert fr["bound_us"] == pytest.approx(10.0)
-    assert mat["bound"] == "compute" and fr["bound"] == "memory"
-    # equal bounds -> measured wall attributed 50/50
-    assert mat["share"] == pytest.approx(0.5)
-    assert mat["attributed_us"] == pytest.approx(20.0)
-    assert row["utilization"] == pytest.approx(0.5)  # 20us bound / 40us wall
-    assert attr["matrix_path"]["attributed_us"] == pytest.approx(20.0)
-    assert attr["fringe_path"]["attributed_us"] == pytest.approx(20.0)
-    assert attr["utilization"] == pytest.approx(0.5)
-
-
-def test_roofline_groups_by_op_tier_sig():
-    attr = roofline_attribution([
-        _rec(sig="a"), _rec(sig="a"), _rec(sig="b"), _rec(tier="xla"),
-    ])
-    keys = [(r["op"], r["tier"], r["sig"]) for r in attr["rows"]]
-    assert sorted(keys) == keys  # deterministic order
-    assert len(keys) == 3
-    by_key = {k: r for k, r in zip(keys, attr["rows"])}
-    assert by_key[("spmm", "pallas", "a")]["calls"] == 2
-
-
-def test_roofline_excludes_traced_by_default():
-    recs = [_rec(measured_us=1e6, traced=True), _rec(measured_us=30.0)]
-    attr = roofline_attribution(recs)
-    assert attr["skipped_traced"] == 1
-    assert attr["measured_us_total"] == pytest.approx(30.0)
-    attr_all = roofline_attribution(recs, include_traced=True)
-    assert attr_all["skipped_traced"] == 0
-    assert attr_all["measured_us_total"] == pytest.approx(1e6 + 30.0)
-
-
-def test_roofline_prometheus_round_trip():
-    attr = roofline_attribution([_rec(measured_us=40.0)])
-    parsed = parse_prometheus_text(roofline_prometheus(attr))
-    base = (("op", "spmm"), ("sig", "aaaa"), ("tier", "pallas"))
-    assert parsed["repro_roofline_calls"][base] == 1.0
-    assert parsed["repro_roofline_measured_us"][base] == pytest.approx(40.0)
-    mat = tuple(sorted(base + (("path", "matrix"),)))
-    assert parsed["repro_roofline_bound_us"][mat] == pytest.approx(10.0)
-    agg = (("op", "_all"), ("path", "fringe"), ("sig", "_all"),
-           ("tier", "_all"))
-    assert parsed["repro_roofline_attributed_us"][agg] == pytest.approx(20.0)
+def test_span_times_ring_bounded():
+    times = SpanTimes(capacity=3)
+    for ns in (5, 1, 4, 2, 3):
+        times.record("x", ns)
+    assert times.durations_ns("x") == [4, 2, 3]
+    assert times.snapshot() == {"x": {"count": 3, "p50_us": 3e-3,
+                                      "max_us": 4e-3}}
+    times.reset()
+    assert times.snapshot() == {}

@@ -1,10 +1,11 @@
-"""Telemetry end-to-end: zero-cost guarantee, roofline, service tracing.
+"""Telemetry end-to-end: zero-cost guarantee, phase spans, service tracing.
 
-The contract under test (ISSUE 9): turning ``SpmmConfig.telemetry`` on is
-host-side only — bit-identical numeric output, zero plan-signature
-changes, zero extra retraces, zero extra device dispatches — while the
-``repro.obs`` snapshot gains per-request traces and the matrix-path vs
-fringe-path roofline attribution.  Also pins the legacy counter surfaces
+The contract under test: turning ``SpmmConfig.telemetry`` on is host-side
+only — bit-identical numeric output, zero plan-signature changes, zero
+extra retraces, zero extra device dispatches, and no call that
+synchronizes — while the ``repro.obs`` snapshot gains per-request traces.
+Every dispatch opens one ``repro.lookup`` and one ``repro.launch`` phase
+span, telemetry or not.  Also pins the legacy counter surfaces
 (``SpmmService.health()`` schema, the ``fused_trace_count`` /
 ``dispatch_count`` / ``prepare_call_count`` hooks) that now ride on the
 shared registry, and regression-tests the health-table snapshot/reset
@@ -14,13 +15,12 @@ import dataclasses
 import threading
 
 import numpy as np
-import pytest
 
 import repro.obs as obs
 import repro.sparse as sp
 from repro.core import spmm
 from repro.exec.health import HealthTable
-from repro.obs import PROFILER, TRACES, parse_prometheus_text
+from repro.obs import SPAN_TIMES, TRACES
 from repro.serve import SpmmService
 from conftest import make_sparse
 
@@ -83,65 +83,58 @@ def test_telemetry_bit_identical_no_extra_traces_or_dispatches(rng):
 def test_telemetry_off_records_nothing(rng):
     a, p_off, _ = _prepare_pair(rng)
     b = rng.randn(a.shape[1], 8).astype(np.float32)
-    PROFILER.reset()
+    A = sp.from_plan(p_off)
+    TRACES.reset()
     spmm.execute(p_off, b)
-    assert len(PROFILER) == 0
+    sp.spmm(A, b)
+    svc = SpmmService(p_off.config, max_batch=2)
+    _, rows, cols, vals = make_sparse(rng, 40, 30, 0.1)
+    svc.register("g", rows, cols, vals, (40, 30))
+    ticket = svc.submit("g", rng.randn(30, 8).astype(np.float32))
+    svc.flush()
+    svc.fetch(ticket)
+    assert len(TRACES) == 0
 
 
-# ---------------------------------------------------------------------------
-# roofline attribution
-# ---------------------------------------------------------------------------
+def test_telemetry_on_never_synchronizes(rng, monkeypatch):
+    import jax
+
+    def no_sync(*_a, **_k):
+        raise AssertionError("telemetry synchronized a dispatch")
+
+    _, p_off, p_on = _prepare_pair(rng)
+    b = rng.randn(p_on.shape[1], 8).astype(np.float32)
+    spmm.execute(p_on, b)  # compile outside the patch
+    svc = SpmmService(p_on.config, max_batch=2)
+    _, rows, cols, vals = make_sparse(rng, 40, 30, 0.1)
+    svc.register("g", rows, cols, vals, (40, 30))
+    monkeypatch.setattr(jax, "block_until_ready", no_sync)
+    TRACES.reset()
+    spmm.execute(p_on, b)
+    sp.spmm(sp.from_plan(p_on), b)
+    ticket = svc.submit("g", rng.randn(30, 8).astype(np.float32))
+    svc.flush()
+    out = svc.fetch(ticket)
+    monkeypatch.undo()
+    assert np.asarray(out).shape == (40, 8)
+    assert [t["name"] for t in TRACES.snapshot()] == ["facade:spmm",
+                                                     "spmm:g"]
 
 
-def test_roofline_snapshot_for_profiled_run(rng):
-    # unique shape -> fresh signature -> the first call really traces;
-    # alpha=0.5 routes the sparse tail onto the fringe (vector) path so
-    # both engines carry modeled work
-    a, _, p_on = _prepare_pair(rng, m=97, k=83, alpha=0.5)
-    b = rng.randn(a.shape[1], 16).astype(np.float32)
-    PROFILER.reset()
-    spmm.execute(p_on, b)  # first call traces -> excluded from the report
-    for _ in range(3):
-        spmm.execute(p_on, b)
-
-    snap = obs.snapshot()
-    attr = snap["roofline"]
-    assert attr["skipped_traced"] >= 1
-    (row,) = attr["rows"]
-    assert row["op"] == "spmm" and row["tier"] == "xla"
-    assert row["calls"] == 3
-    assert row["measured_us"] > 0
-    # the prepared matrix has dense rows and a sparse tail, so both engine
-    # paths carry modeled work
-    assert row["paths"]["matrix"]["flops"] > 0
-    assert row["paths"]["fringe"]["flops"] > 0
-    # the CPU is not in the device peak table, so no roofline share is
-    # computed for it (never against another device's peaks)
-    assert row["peaks"] == {}
-    for p in ("matrix", "fringe"):
-        assert row["paths"][p]["share"] is None
-        assert row["paths"][p]["bound_us"] is None
-        assert attr[f"{p}_path"]["attributed_us"] is None
-    assert row["utilization"] is None and attr["utilization"] is None
-
-    # Prometheus export round-trips the same numbers
-    parsed = parse_prometheus_text(obs.prometheus_text())
-    key = (("op", "spmm"), ("sig", row["sig"]), ("tier", "xla"))
-    assert parsed["repro_roofline_calls"][key] == 3.0
-    assert parsed["repro_roofline_measured_us"][key] == pytest.approx(
-        row["measured_us"])
-
-
-def test_sddmm_and_spspmm_profiled(rng):
-    a, rows, cols, vals = make_sparse(rng, 48, 48, 0.1)  # square: A @ A
-    A = sp.from_coo(rows, cols, vals, a.shape, impl="xla", telemetry=True)
-    x = rng.randn(48, 8).astype(np.float32)
+def test_dispatch_spans_one_each_per_call(rng):
+    a, rows, cols, vals = make_sparse(rng, 64, 48, 0.1, n_dense_rows=2)
+    A = sp.from_coo(rows, cols, vals, a.shape, impl="xla")
+    b = rng.randn(48, 8).astype(np.float32)
+    x = rng.randn(64, 8).astype(np.float32)
     y = rng.randn(8, 48).astype(np.float32)
-    PROFILER.reset()
+    sp.spmm(A, b)
     sp.sddmm(A, x, y)
-    sp.spspmm(A, A.with_values(np.abs(vals)))
-    ops = {r.op for r in PROFILER.records()}
-    assert "sddmm" in ops and "spspmm" in ops
+    SPAN_TIMES.reset()
+    for _ in range(3):
+        sp.spmm(A, b)
+        sp.sddmm(A, x, y)
+    for name in ("call", "lookup", "launch"):
+        assert len(SPAN_TIMES.durations_ns(name)) == 6, name
 
 
 # ---------------------------------------------------------------------------
@@ -189,8 +182,7 @@ def test_service_span_structure_pinned(rng):
     assert tr["attrs"]["ticket"] == ticket
     assert tr["attrs"]["outcome"] == "ok"
     assert [s["name"] for s in tr["spans"]] == [
-        "admit", "queue_wait", "batch_assembly", "dispatch",
-        "block_until_ready", "fetch",
+        "admit", "queue_wait", "batch_assembly", "dispatch", "fetch",
     ]
     # the counter clock ticks monotonically, so the spans chain in order
     for s in tr["spans"]:

@@ -133,3 +133,44 @@ CASES = {
 def test_kernel_compiles_for_v5e(case, one_chip):
     spec = CASES[case]
     _compile(spec["fn"], one_chip, spec["shapes"], **spec["static"])
+
+
+def test_fused_body_scopes_name_the_tpu_kernels(one_chip):
+    """The fused executor compiled for a v5e keeps each kernel's stage in
+    its ``op_name``: the tile stream under ``matrix_path``, the K-sharded
+    fringe under ``fringe_path`` (the names a chip trace reads)."""
+    import re
+
+    import numpy as np
+
+    from repro.core import plan_ir, spmm
+    from repro.core.cost_model import fringe_resident_bytes
+    from repro.exec.pipeline import build_executor
+
+    rng = np.random.default_rng(0)
+    m = k = 2048
+    # 256 dense band rows on the matrix path, a random tail on the fringe
+    r1 = np.repeat(np.arange(256), 64)
+    c1 = (r1 + np.tile(np.arange(64), 256)) % k
+    r2 = rng.integers(256, m, 6000)
+    c2 = rng.integers(0, k, 6000)
+    rows, cols = np.concatenate([r1, r2]), np.concatenate([c1, c2])
+    vals = rng.standard_normal(rows.size).astype(np.float32)
+    cfg = spmm.SpmmConfig(
+        impl="pallas", fringe_vmem_budget=fringe_resident_bytes(k, m, 128) - 1)
+    plan = spmm.prepare(rows, cols, vals, (m, k), cfg)
+    assert plan.has_core and plan.fringe_tier == "ksharded"
+    leaves = (*plan_ir.plan_leaves(plan), jnp.zeros((k, 128), F32))
+    args = [jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+            for x in leaves]
+    text = build_executor(plan.signature()).lower(*args).compile().as_text()
+    kernels = {}
+    for line in text.splitlines():
+        if 'custom_call_target="tpu_custom_call"' not in line:
+            continue
+        name = line.split(" = ", 1)[0].strip().lstrip("%")
+        op_name = re.search(r'op_name="([^"]*)"', line)
+        kernels[name] = op_name.group(1) if op_name else ""
+    scope_of = {re.sub(r"\.\d+$", "", n): o for n, o in kernels.items()}
+    assert "/matrix_path/" in scope_of["dense_tile_spmm"], kernels
+    assert "/fringe_path/" in scope_of["gather_spmm_ksharded"], kernels

@@ -19,7 +19,7 @@ above itself:
                              anything, imported by nothing below)
 
 ``repro.errors`` (a top-level module), ``repro.obs`` (the telemetry
-registry/trace/profiler package) and ``repro.robust`` sit at the very
+registry, trace ring and phase spans) and ``repro.robust`` sit at the very
 bottom: any layer may import them, they import nothing above (``obs``
 imports only itself; ``robust`` may import ``errors``, ``obs`` and
 itself).  Keeping ``obs`` dependency-free is what lets every counter
