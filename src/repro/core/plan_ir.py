@@ -44,7 +44,9 @@ from .cost_model import select_fringe_tier
 # instead of misinterpreting their arrays.
 # v2: structured-sparsity payload leaves (N:M + bitmap) and the trailing
 # (matrix_format, format_params) signature fields.
-PLAN_FORMAT_VERSION = 2
+# v3: XLA-tier fringes are stored degree-bucketed (padded, renumbered
+# rows) and the signature ends with the bucket ladder.
+PLAN_FORMAT_VERSION = 3
 
 PATH_CORE = 0
 PATH_FRINGE = 1
@@ -304,11 +306,13 @@ class NeutronPlan:
     step_col: jax.Array      # (T,) int32
     flat_values: jax.Array   # (T, bm, bk)
     core_row_map: jax.Array  # (num_windows*bm,) int32 -> original row (-1 pad)
-    # vector path: packed row-sorted fringe COO
+    # vector path: packed row-sorted fringe COO, or its degree-bucketed
+    # relayout when fringe_buckets is set (bucket_fringe_rows)
     fringe_rows: jax.Array   # (nnz_f,) int32 packed ids
     fringe_cols: jax.Array   # (nnz_f,) int32
     fringe_vals: jax.Array   # (nnz_f,)
-    fringe_row_ids: jax.Array  # (n_fringe_rows,) int32 original ids
+    fringe_row_ids: jax.Array  # (n_fringe_rows,) int32 original ids; -1
+    #                            for a row that only fills a bucket
     col_perm: jax.Array      # (K,) int32 — B row perm (identity unless reorder_cols)
     # scatter-free merge: inverse row maps (original row -> packed slot or -1)
     gather_src_matrix: jax.Array  # (M,) int32 -> packed matrix-path row
@@ -342,6 +346,9 @@ class NeutronPlan:
     matrix_format: str = "general"
     # (n, m) for "nm"; (num_words, row_cap) for "bitmap"; (0, 0) general
     format_params: Tuple[int, int] = (0, 0)
+    # ((n_rows_b, width_b), ...) when the fringe stream is degree-bucketed
+    # (bucket_fringe_rows; XLA-tier single-device plans), () otherwise
+    fringe_buckets: Tuple[Tuple[int, int], ...] = ()
     # host-side COO->slot inverse maps for dynamic value updates.  Not a
     # pytree leaf and not aux data (numpy payloads are unhashable): a plan
     # round-tripped through tree operations comes back with maps=None and
@@ -362,7 +369,7 @@ class NeutronPlan:
         return leaves, (
             self.shape, self.config, self.stats,
             self.fringe_tier, self.fringe_bk,
-            self.matrix_format, self.format_params,
+            self.matrix_format, self.format_params, self.fringe_buckets,
         )
 
     @classmethod
@@ -390,7 +397,9 @@ class NeutronPlan:
 
         Includes the vector-path dispatch tier and its k-block size: two
         plans differing only in tier (e.g. from different VMEM budgets)
-        must not alias one cached executor.  The leading element is
+        must not alias one cached executor; likewise the fringe's bucket
+        ladder, whose shapes the XLA gather-reduce is traced with.  The
+        leading element is
         ``PLAN_FORMAT_VERSION`` so executors (and the persistent registry,
         which keys entries by signature) never cross plan-layout versions.
         """
@@ -405,6 +414,7 @@ class NeutronPlan:
             int(self.fringe_kb_chunk.shape[0]),
             int(self.fringe_kb_rows.shape[0]),
             self.matrix_format, tuple(self.format_params),
+            tuple(self.fringe_buckets),
         )
 
 
@@ -621,6 +631,77 @@ def bucket_fringe_kblocks(
     pos_of_packed = np.empty(kbs.size, np.int64)
     pos_of_packed[order_kb] = dest
     return kb_chunk, kb_rows, kb_cols, kb_vals, pos_of_packed
+
+
+# --- degree-bucketed fringe stream ------------------------------------------
+
+
+# rows per bucket are a multiple of the sublane tile, so a bucket's
+# (width, n_rows, N) gather and its 2-D form (width * n_rows, N) share one
+# tiled layout and reshape without a relayout copy
+BUCKET_ROW_ALIGN = 8
+
+
+def bucket_fringe_rows(
+    pr: np.ndarray, pc: np.ndarray, pv: np.ndarray,
+) -> Tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray, np.ndarray,
+           Tuple[Tuple[int, int], ...]]:
+    """Relayout the packed row-sorted fringe as degree buckets (ELL).
+
+    Each packed row gets a width, its degree rounded up to a power of two;
+    rows are renumbered bucket-major by width ascending (original order
+    within a bucket), each bucket is filled up to a multiple of
+    ``BUCKET_ROW_ALIGN`` rows with rows that hold no nonzero, and each
+    row's run is padded to its width with ``(row, col, 0.0)`` entries,
+    ``col`` the row's first column (0 for a filler row).  Bucket ``b`` is
+    the next ``n_rows_b * width_b`` slots of the stream, stored
+    width-major: slot ``j * n_rows_b + i`` holds entry ``j`` of the
+    bucket's row ``i``, so the XLA gather-reduce
+    (``ref.bucketed_gather_spmm``) reads each width position as one
+    contiguous ``(n_rows_b,)`` slice.  The result is still a COO stream
+    whose padding adds zero (rows unsorted within a bucket), so every
+    scatter consumer reads it unchanged.
+
+    Returns ``(rows, cols, vals, row_order, slot_of_entry, ladder)``:
+    ``row_order[new]`` is the old packed row of each new one (-1 for a
+    filler row), ``slot_of_entry[i]`` the slot of packed entry ``i``, and
+    ``ladder = ((n_rows_b, width_b), ...)``.
+    """
+    n_rows = int(pr[-1]) + 1
+    deg = np.bincount(pr, minlength=n_rows)
+    # 2**bit_length(deg - 1): the smallest power of two >= deg
+    width = np.left_shift(1, np.frexp(deg - 1)[1].astype(np.int64))
+    order = np.argsort(width, kind="stable")
+    widths, counts = np.unique(width[order], return_counts=True)
+    align = BUCKET_ROW_ALIGN
+    n_b = -(-counts // align) * align
+    first_row = np.cumsum(n_b) - n_b
+    first_slot = np.cumsum(n_b * widths) - n_b * widths
+    # old row -> bucket, new row
+    bkt = np.empty(n_rows, np.int64)
+    bkt[order] = np.repeat(np.arange(widths.size), counts)
+    new_of_old = np.empty(n_rows, np.int64)
+    new_of_old[order] = (np.arange(n_rows)
+                         - np.repeat(np.cumsum(counts) - counts, counts)
+                         + np.repeat(first_row, counts))
+    row_order = np.full(int(n_b.sum()), -1, np.int64)
+    row_order[new_of_old] = np.arange(n_rows)
+    # every slot's row: bucket b repeats its rows width_b times
+    slot_bkt = np.repeat(np.arange(widths.size), n_b * widths)
+    local = np.arange(slot_bkt.size) - first_slot[slot_bkt]
+    rows = (first_row[slot_bkt] + local % n_b[slot_bkt]).astype(np.int32)
+    entry_start = np.cumsum(deg) - deg
+    first_col = np.zeros(row_order.size, np.int32)
+    first_col[new_of_old] = pc[entry_start]
+    cols = first_col[rows]
+    b = bkt[pr]
+    slot = (first_slot[b] + (np.arange(pr.size) - entry_start[pr]) * n_b[b]
+            + new_of_old[pr] - first_row[b])
+    cols[slot] = pc
+    vals = np.zeros(rows.size, pv.dtype)
+    vals[slot] = pv
+    ladder = tuple((int(n), int(w)) for n, w in zip(n_b, widths))
+    return rows, cols, vals, row_order, slot, ladder
 
 
 # --- update-map construction ------------------------------------------------

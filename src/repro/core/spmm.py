@@ -203,9 +203,15 @@ def prepare(
     config: SpmmConfig = SpmmConfig(),
     cost_model: Optional[EngineCostModel] = None,
     *,
-    _tune_tile_shape: bool = True,
+    _shard_part: bool = False,
 ) -> NeutronPlan:
-    """Host-side preprocessing (one-time; amortized across epochs)."""
+    """Host-side preprocessing (one-time; amortized across epochs).
+
+    ``_shard_part`` marks a per-shard sub-prepare of ``prepare_sharded``:
+    the tile shape is already resolved at the global shape, and the fringe
+    keeps the plain row-sorted stream (a bucket ladder would have to be
+    mesh-uniform).
+    """
     m, k = shape
     rows, cols, vals = plan_ir.validate_coo(rows, cols, vals, shape)
     _PREPARES.inc()
@@ -218,8 +224,8 @@ def prepare(
     # tuned (bm, bk) applies before partitioning — the tile shape drives
     # window costs, the core/fringe split, and every static plan shape.
     # prepare_sharded resolves it once at the global shape and passes
-    # _tune_tile_shape=False so per-shard sub-prepares stay mesh-uniform.
-    if _tune_tile_shape and config.autotune:
+    # _shard_part=True so per-shard sub-prepares stay mesh-uniform.
+    if not _shard_part and config.autotune:
         ts = cm.tile_shape(int(m), int(k), config.bn, int(rows.shape[0]))
         if ts is not None:
             config = dataclasses.replace(config, bm=int(ts[0]), bk=int(ts[1]))
@@ -365,7 +371,18 @@ def prepare(
         k_pad, int(fringe_row_ids.shape[0]), config.bn,
         vmem_budget=config.fringe_vmem_budget, nnz=int(f_rows.size),
     )
-    # the bucketed stream is only consumed by the pallas kernels; xla-impl
+    # 4c) a fringe that runs on XLA is laid out degree-bucketed (ELL): the
+    # executor then gathers and reduces each bucket at a fixed width, with
+    # no per-call sort and no row scatter-add
+    fringe_buckets = ()
+    if f_rows.size and not _shard_part and (
+            fringe_tier == "xla" or config.impl == "xla"):
+        pr, pc, pv, row_order, slot, fringe_buckets = (
+            plan_ir.bucket_fringe_rows(pr, pc, pv))
+        fringe_row_ids = np.where(
+            row_order >= 0, fringe_row_ids[np.maximum(row_order, 0)], -1)
+        fringe_pos = slot[fringe_pos]
+    # the k-bucketed stream is only consumed by the pallas kernels; xla-impl
     # plans skip the bucketing sort/scatter passes (tier is still recorded)
     if fringe_tier == "ksharded" and f_rows.size and config.impl != "xla":
         kb_chunk, kb_rows, kb_cols, kb_vals, kb_pos_of_packed = (
@@ -386,9 +403,8 @@ def prepare(
     gather_src_matrix[core_row_map[valid_slots]] = valid_slots
     gather_src_vector = np.full(m, -1, np.int32)
     if f_rows.size:
-        gather_src_vector[fringe_row_ids] = np.arange(
-            fringe_row_ids.size, dtype=np.int32
-        )
+        packed_ids = np.flatnonzero(fringe_row_ids >= 0)
+        gather_src_vector[fringe_row_ids[packed_ids]] = packed_ids
     update_maps = plan_ir.build_update_maps(
         rows, cols, vals, shape, part, core_lin, fringe_pos,
         kb_pos_of_packed,
@@ -410,6 +426,10 @@ def prepare(
         ("k_pad", k_pad),
         ("fringe_tier", fringe_tier),
         ("fringe_bk", int(fringe_bk)),
+        # degree buckets of the fringe stream (0: not bucketed) and its
+        # length; fringe_slots / fringe_nnz is the bucketing's padding
+        ("fringe_buckets", len(fringe_buckets)),
+        ("fringe_slots", int(pr.shape[0]) if f_rows.size else 0),
         ("matrix_format", matrix_format),
         ("format_params", tuple(format_params)),
         # zero fraction of the *active* tiles — the padding waste the
@@ -444,6 +464,7 @@ def prepare(
         fringe_bk=int(fringe_bk),
         matrix_format=matrix_format,
         format_params=tuple(format_params),
+        fringe_buckets=fringe_buckets,
         update_maps=update_maps,
     )
 
@@ -540,7 +561,7 @@ def prepare_sharded(
 
     if shard_axis == "rhs":
         plan = prepare(rows, cols, vals, shape, shard_config, cm,
-                       _tune_tile_shape=False)
+                       _shard_part=True)
         um = plan.update_maps
         smaps = ShardedUpdateMaps(
             shape=tuple(shape), rows=um.rows, cols=um.cols, vals=um.vals,
@@ -610,7 +631,7 @@ def prepare_sharded(
         shard_idx.append(np.flatnonzero(mask))
         plans.append(prepare(
             local_rows, cols[mask], vals[mask], (m_loc_max, k), sub_cfg, cm,
-            _tune_tile_shape=False,
+            _shard_part=True,
         ))
 
     # --- mesh-uniform static structure: pad every leaf to the max ---------
@@ -653,7 +674,7 @@ def prepare_sharded(
         (m_loc_max, k), cfg.bm, cfg.bk, cfg.bn, cfg.impl, cfg.reorder_cols,
         cfg.fringe_chunk, nw_kernel, t_max, nnzf_max, nfr_max,
         has_core, has_fringe, u_tier, int(u_bk), nch_max, nnzkb_max,
-        "general", (0, 0),
+        "general", (0, 0), (),
     )
 
     # COO->slot maps: shard-local sub-plan maps (padding is prefix-

@@ -159,6 +159,7 @@ class PlanRegistry:
             "fringe_bk": plan.fringe_bk,
             "matrix_format": plan.matrix_format,
             "format_params": list(plan.format_params),
+            "fringe_buckets": [list(nw) for nw in plan.fringe_buckets],
             "signature": repr(plan.signature()),
             "coo_hash": coo_fingerprint(
                 rows, cols, vals, plan.shape, plan.config
@@ -351,6 +352,8 @@ class PlanRegistry:
                 fringe_bk=int(meta["fringe_bk"]),
                 matrix_format=meta.get("matrix_format", "general"),
                 format_params=tuple(meta.get("format_params", (0, 0))),
+                fringe_buckets=tuple(
+                    (int(n), int(w)) for n, w in meta["fringe_buckets"]),
                 update_maps=maps,
             )
         except (KeyError, TypeError, ValueError) as e:

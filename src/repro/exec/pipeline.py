@@ -61,6 +61,13 @@ _BUILDS = REGISTRY.counter(
     "exec_executor_builds_total",
     "executors actually constructed (cache hits skip the build)",
     labelnames=("kind",))
+# "bucketed": the degree-bucketed gather-reduce over a plan's bucket ladder;
+# "scatter": a stream without one (segment-sum scatter on XLA, or a Pallas
+# fringe kernel)
+_FRINGE_FORMULATION = REGISTRY.counter(
+    "exec_fringe_formulation_total",
+    "fused-body traces with a fringe, by the fringe stream's formulation",
+    labelnames=("formulation",))
 
 
 def _fused_body(sig: Tuple, densify_occupancy: Optional[float] = None):
@@ -79,7 +86,7 @@ def _fused_body(sig: Tuple, densify_occupancy: Optional[float] = None):
     (_version, shape, bm, bk, bn, impl, reorder_cols, fringe_chunk,
      num_windows, _num_steps, _nnz_f, n_fringe_rows, has_core, has_fringe,
      fringe_tier, fringe_bk, _n_chunks, _nnz_kb,
-     matrix_format, format_params) = sig
+     matrix_format, format_params, fringe_buckets) = sig
     m, k = shape
 
     def _run(step_window, step_col, flat_values, fringe_rows, fringe_cols,
@@ -87,6 +94,9 @@ def _fused_body(sig: Tuple, densify_occupancy: Optional[float] = None):
              kb_chunk, kb_rows, kb_cols, kb_vals,
              nm_values, nm_codes, bitmap_words, bitmap_values, b):
         record_fused_trace(sig)
+        if has_fringe:
+            _FRINGE_FORMULATION.inc(
+                formulation="bucketed" if fringe_buckets else "scatter")
         if impl != "xla":  # pallas tiers lower here, at trace time
             HARNESS.fire("pallas_lowering", context=sig)
         n = b.shape[1]
@@ -131,7 +141,7 @@ def _fused_body(sig: Tuple, densify_occupancy: Optional[float] = None):
                     num_rows=n_fringe_rows, bn=bn, impl=impl,
                     chunk=fringe_chunk, tier=fringe_tier, bk=fringe_bk,
                     kb_chunk=kb_chunk, kb_rows=kb_rows,
-                    kb_cols=kb_cols, kb_vals=kb_vals,
+                    kb_cols=kb_cols, kb_vals=kb_vals, buckets=fringe_buckets,
                 )[:, :n]
         with jax.named_scope(MERGE):
             c = None
@@ -161,7 +171,7 @@ def _sddmm_body(sig: Tuple):
     (_version, shape, bm, bk, _bn, impl, reorder_cols, fringe_chunk,
      _num_windows, _num_steps, _nnz_f, _n_fringe_rows, has_core, has_fringe,
      _fringe_tier, _fringe_bk, _n_chunks, _nnz_kb,
-     _matrix_format, _format_params) = untag_sig(sig)
+     _matrix_format, _format_params, _fringe_buckets) = untag_sig(sig)
     _m, k = shape
     # nnz / nnz_f key the cache (shapes come from the arrays at trace time);
     # the budget must live in the sig so equal-structure plans with

@@ -222,8 +222,22 @@ def bitmap_stream_spmm(
     )
 
 
+def _xla_fringe(rows, cols, vals, b, num_rows, chunk, buckets):
+    if not buckets:
+        return ref.ref_gather_spmm(rows, cols, vals, b, num_rows, chunk=chunk)
+    if (sum(n for n, _ in buckets) != num_rows
+            or sum(n * w for n, w in buckets) != cols.shape[0]):
+        raise ValueError(
+            f"bucket ladder {buckets!r} does not cover the stream: "
+            f"{num_rows} rows, {cols.shape[0]} slots"
+        )
+    return ref.bucketed_gather_spmm(cols, vals, b, buckets)
+
+
 @functools.partial(
-    jax.jit, static_argnames=("num_rows", "bn", "impl", "chunk", "tier", "bk")
+    jax.jit,
+    static_argnames=("num_rows", "bn", "impl", "chunk", "tier", "bk",
+                     "buckets"),
 )
 def fringe_spmm(
     rows: jax.Array,
@@ -241,14 +255,24 @@ def fringe_spmm(
     kb_rows: jax.Array | None = None,
     kb_cols: jax.Array | None = None,
     kb_vals: jax.Array | None = None,
+    buckets: tuple = (),
 ) -> jax.Array:
     """Vector-engine path; returns packed (num_rows, N) fp32.
 
+    Where the fringe runs on XLA (``impl="xla"``, or the "xla" tier), two
+    formulations exist.  With a bucket ladder ``buckets`` — ``((n_rows_b,
+    width_b), ...)``, which ``prepare`` builds for single-device plans
+    whose fringe runs on XLA and lays the stream out for — each bucket is
+    gathered and reduced at its fixed width (``ref.bucketed_gather_spmm``):
+    no sort and no scatter.  Without one, ``ref.ref_gather_spmm`` sums the
+    products with an unsorted segment sum; it stays the oracle of both.
+
     ``chunk`` is the per-grid-step nonzero count of the chunked gather
-    kernel; for the XLA path it bounds the gather intermediate (None means
-    the one-shot vectorized formulation).  The pallas kernel unrolls its
-    chunk loop in python, so large XLA-oriented values (thousands) are
-    clamped to a compile-friendly unroll factor there.
+    kernel; for the scatter formulation it bounds the gather intermediate
+    (None means the one-shot vectorized formulation); the bucketed one
+    ignores it.  The pallas kernel unrolls its chunk loop in python, so
+    large XLA-oriented values (thousands) are clamped to a compile-friendly
+    unroll factor there.
 
     Pallas impls dispatch across three VMEM tiers
     (core/cost_model.select_fringe_tier): "resident" keeps the full (K, bn)
@@ -270,7 +294,7 @@ def fringe_spmm(
     if chunk is not None and chunk < 1:
         raise ValueError(f"chunk must be a positive nonzero count, got {chunk}")
     if impl == "xla":
-        return ref.ref_gather_spmm(rows, cols, vals, b, num_rows, chunk=chunk)
+        return _xla_fringe(rows, cols, vals, b, num_rows, chunk, buckets)
     if tier == "auto":
         from ..core.cost_model import select_fringe_tier
 
@@ -299,7 +323,7 @@ def fringe_spmm(
             num_rows=num_rows, bk=bk, bn=bn, chunk=effective_chunk(chunk),
             interpret=(impl == "pallas_interpret"),
         )
-    return ref.ref_gather_spmm(rows, cols, vals, b, num_rows, chunk=chunk)
+    return _xla_fringe(rows, cols, vals, b, num_rows, chunk, buckets)
 
 
 @functools.partial(

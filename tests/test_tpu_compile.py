@@ -29,6 +29,12 @@ BIG_FRINGE = 1 << 20
 # ogbn-arxiv at its published size, as chip_smoke.py phase a prepares it
 ARXIV_K = 169_344          # 169,343 padded to the bk=64 multiple
 ARXIV_STEPS = 2_646        # tile steps of its matrix path (bm=128, bk=64)
+# its XLA-tier fringe as prepare buckets it, (rows, width) per bucket:
+# 169,292 rows (169,320 with each bucket filled to a multiple of 8),
+# 1,397,552 slots for 1,051,170 nonzeros
+ARXIV_LADDER = ((18_368, 1), (62_280, 2), (43_016, 4), (24_440, 8),
+                (11_888, 16), (5_488, 32), (2_304, 64), (920, 128),
+                (376, 256), (168, 512), (72, 1_024))
 
 
 @pytest.fixture(scope="module")
@@ -174,3 +180,22 @@ def test_fused_body_scopes_name_the_tpu_kernels(one_chip):
     scope_of = {re.sub(r"\.\d+$", "", n): o for n, o in kernels.items()}
     assert "/matrix_path/" in scope_of["dense_tile_spmm"], kernels
     assert "/fringe_path/" in scope_of["gather_spmm_ksharded"], kernels
+
+
+def test_bucketed_fringe_compiles_without_sort_or_scatter(one_chip):
+    """The XLA fringe at ogbn-arxiv's bucket ladder and width 128, as the
+    fused body runs it: gathers and reductions only, no sort and no
+    scatter (the unbucketed stream compiles to both)."""
+    from repro.kernels import ops
+
+    rows = sum(n for n, _ in ARXIV_LADDER)
+    slots = sum(n * w for n, w in ARXIV_LADDER)
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in (
+        ((slots,), I32), ((slots,), I32), ((slots,), F32),
+        ((ARXIV_K, 128), F32))]
+    compiled = ops.fringe_spmm.lower(
+        *args, num_rows=rows, bn=128, impl="pallas", tier="xla",
+        buckets=ARXIV_LADDER).compile()
+    text = compiled.as_text()
+    assert " gather(" in text
+    assert " sort(" not in text and " scatter(" not in text
