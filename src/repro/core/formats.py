@@ -386,13 +386,21 @@ def detect_nm_pattern(
     cols = np.asarray(cols, np.int64)
     if rows.size == 0:
         return None
-    # duplicates share a cell: dedupe (row, col) before counting
-    cell = np.unique(rows * np.int64(k) + cols)
-    ucols = cell % k
+    # duplicates share a cell: dedupe (row, col) before counting; input
+    # that is already row-major and unique (prepare's usual) skips the sort
+    cell = rows * np.int64(k) + cols
+    if np.all(cell[1:] > cell[:-1]):
+        urows, ucols = rows, cols
+    else:
+        cell = np.unique(cell)
+        urows, ucols = cell // k, cell % k
     best = None
     for m_pat in candidates:
-        counts = np.unique((cell // k) * np.int64((k + m_pat - 1) // m_pat)
-                           + ucols // m_pat, return_counts=True)[1]
+        # group keys of sorted cells are sorted: count them by runs
+        group = urows * np.int64((k + m_pat - 1) // m_pat) + ucols // m_pat
+        starts = np.flatnonzero(np.concatenate(
+            [[True], group[1:] != group[:-1]]))
+        counts = np.diff(np.append(starts, group.size))
         n_pat = int(counts.max())
         if n_pat > NM_MAX_N or n_pat > m_pat * NM_MAX_KEEP_FRACTION:
             continue

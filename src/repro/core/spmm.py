@@ -30,7 +30,6 @@ carries the one documented allowance for this facade.
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Any, List, Optional, Tuple
 
 import jax
@@ -52,7 +51,7 @@ from .plan_ir import (  # noqa: F401  (public re-exports; layout owned by plan_i
     SpmmConfig, UpdateMaps,
 )
 
-from ..obs import REGISTRY
+from ..obs import REGISTRY, span
 
 _PREPARES = REGISTRY.counter(
     "core_prepares_total", "host-side prepare() preprocessing runs")
@@ -207,11 +206,23 @@ def prepare(
 ) -> NeutronPlan:
     """Host-side preprocessing (one-time; amortized across epochs).
 
+    Runs under the ``repro.prepare`` span, its phases under ``partition``,
+    ``reorder``, ``pack`` and ``upload`` (:class:`repro.obs.span`); the
+    plan stats ``t_partition_s``, ``t_reorder_s`` and ``t_pack_s`` are
+    those spans' durations.
+
     ``_shard_part`` marks a per-shard sub-prepare of ``prepare_sharded``:
     the tile shape is already resolved at the global shape, and the fringe
     keeps the plain row-sorted stream (a bucket ladder would have to be
     mesh-uniform).
     """
+    with span("prepare"):
+        return _prepare(rows, cols, vals, shape, config, cost_model,
+                        _shard_part)
+
+
+def _prepare(rows, cols, vals, shape, config, cost_model, _shard_part):
+    """The phases of :func:`prepare`, each under a span of its own."""
     m, k = shape
     rows, cols, vals = plan_ir.validate_coo(rows, cols, vals, shape)
     _PREPARES.inc()
@@ -229,187 +240,182 @@ def prepare(
         ts = cm.tile_shape(int(m), int(k), config.bn, int(rows.shape[0]))
         if ts is not None:
             config = dataclasses.replace(config, bm=int(ts[0]), bk=int(ts[1]))
-    t0 = time.perf_counter()
-
     # 1) heterogeneous workload partitioning (§5.2)
-    part = partition.partition_rows_cols(
-        rows, cols, vals, shape, cm, alpha=config.alpha,
-        col_stage=config.enable_col_stage,
-    )
-    t_part = time.perf_counter() - t0
-
+    with span("partition") as s_part:
+        part = partition.partition_rows_cols(
+            rows, cols, vals, shape, cm, alpha=config.alpha,
+            col_stage=config.enable_col_stage,
+        )
     # 2) global-local reordering of the dense core (§6.1).  Only the active
     # (window, k-block) *structure* is computed here — tile values are
     # written once, directly into the flat stream (step 3), instead of
     # materializing a BlockELL values array and re-gathering it.
-    t0 = time.perf_counter()
-    n_core = int(part.core_row_ids.shape[0])
-    nw = (n_core + config.bm - 1) // config.bm
-    nkb = (k + config.bk - 1) // config.bk
-    if n_core:
-        local_of_row = np.full(m, -1, np.int64)
-        local_of_row[part.core_row_ids] = np.arange(n_core)
-        lrows = local_of_row[part.core_rows]
-        ro = reorder.reorder(
-            lrows, part.core_cols, (n_core, k), config.bm, config.bk,
-            enable_global=config.enable_global_reorder,
-            enable_local=config.enable_local_reorder,
-            reorder_cols=config.reorder_cols,
-            max_clusters=config.max_clusters,
-            seed=config.seed,
-        )
-        inv_col = np.empty(k, np.int64)
-        inv_col[ro.col_order] = np.arange(k)
-        ccols = inv_col[part.core_cols]
-        inv_row = np.empty(n_core, np.int64)
-        inv_row[ro.row_order] = np.arange(n_core)
-        prow = inv_row[lrows]
-        st = formats.block_structure_from_coo(
-            prow // config.bm, ccols // config.bk, nw, nkb
-        )
-        block_cols = np.zeros((nw, st.max_blocks), np.int32)
-        block_cols[st.uw, st.slot] = st.ub.astype(np.int32)
-        num_blocks = st.counts
-        cluster_of_window = ro.cluster_of_row[:: config.bm][:nw]
-        col_perm = ro.col_order
-        tile_density = part.core_nnz / max(
-            st.uw.size * config.bm * config.bk, 1
-        )
-    else:
-        st = None
-        block_cols = np.zeros((0, 1), np.int32)
-        num_blocks = np.zeros(0, np.int64)
-        cluster_of_window = np.zeros(0, np.int64)
-        col_perm = np.arange(k, dtype=np.int64)
-        tile_density = 0.0
-    t_reorder = time.perf_counter() - t0
+    with span("reorder") as s_reorder:
+        n_core = int(part.core_row_ids.shape[0])
+        nw = (n_core + config.bm - 1) // config.bm
+        nkb = (k + config.bk - 1) // config.bk
+        if n_core:
+            local_of_row = np.full(m, -1, np.int64)
+            local_of_row[part.core_row_ids] = np.arange(n_core)
+            lrows = local_of_row[part.core_rows]
+            ro = reorder.reorder(
+                lrows, part.core_cols, (n_core, k), config.bm, config.bk,
+                enable_global=config.enable_global_reorder,
+                enable_local=config.enable_local_reorder,
+                reorder_cols=config.reorder_cols,
+                max_clusters=config.max_clusters,
+                seed=config.seed,
+            )
+            inv_col = np.empty(k, np.int64)
+            inv_col[ro.col_order] = np.arange(k)
+            ccols = inv_col[part.core_cols]
+            inv_row = np.empty(n_core, np.int64)
+            inv_row[ro.row_order] = np.arange(n_core)
+            prow = inv_row[lrows]
+            st = formats.block_structure_from_coo(
+                prow // config.bm, ccols // config.bk, nw, nkb
+            )
+            block_cols = np.zeros((nw, st.max_blocks), np.int32)
+            block_cols[st.uw, st.slot] = st.ub.astype(np.int32)
+            num_blocks = st.counts
+            cluster_of_window = ro.cluster_of_row[:: config.bm][:nw]
+            col_perm = ro.col_order
+            tile_density = part.core_nnz / max(
+                st.uw.size * config.bm * config.bk, 1
+            )
+        else:
+            st = None
+            block_cols = np.zeros((0, 1), np.int32)
+            num_blocks = np.zeros(0, np.int64)
+            cluster_of_window = np.zeros(0, np.int64)
+            col_perm = np.arange(k, dtype=np.int64)
+            tile_density = 0.0
+    # 3) reuse-ordered flat tile stream (§6.2), then the fringe stream and
+    # the merge's row maps
+    with span("pack") as s_pack:
+        if config.enable_reuse_order and nw:
+            plan_r = reuse.plan_window_order(
+                block_cols, num_blocks, np.asarray(cluster_of_window)
+            )
+            worder = plan_r.window_order
+            reuse_factor = plan_r.reuse_factor
+        else:
+            worder = np.arange(nw, dtype=np.int64)
+            reuse_factor = 1.0
+        if st is not None and st.uw.size:
+            # pair p of window w occupies stream position start(w) + slot(p);
+            # nonzeros then land at (their pair's step, row%bm, col%bk) via one
+            # flat scatter-add — no per-window python loop, no value re-gather
+            cnt = num_blocks[worder]
+            total = int(cnt.sum())
+            starts_w = np.zeros(nw, np.int64)
+            starts_w[worder] = np.cumsum(cnt) - cnt
+            step_of_pair = starts_w[st.uw] + st.slot
+            step_window = np.zeros(total, np.int32)
+            step_window[step_of_pair] = st.uw.astype(np.int32)
+            step_col = np.zeros(total, np.int32)
+            step_col[step_of_pair] = st.ub.astype(np.int32)
+            lin = (
+                step_of_pair[st.inv_idx] * config.bm + prow % config.bm
+            ) * config.bk + ccols % config.bk
+            flat = np.zeros(total * config.bm * config.bk, np.float32)
+            np.add.at(flat, lin, part.core_vals.astype(np.float32))
+            flat_values = flat.reshape(total, config.bm, config.bk)
+            core_lin = lin
+        else:  # degenerate all-fringe matrix: one zero tile keeps shapes static
+            step_window = np.zeros(1, np.int32)
+            step_col = np.zeros(1, np.int32)
+            flat_values = np.zeros((1, config.bm, config.bk), np.float32)
+            core_lin = np.zeros(0, np.int64)
 
-    # 3) reuse-ordered flat tile stream (§6.2)
-    t0 = time.perf_counter()
-    if config.enable_reuse_order and nw:
-        plan_r = reuse.plan_window_order(
-            block_cols, num_blocks, np.asarray(cluster_of_window)
+        # 3b) structured matrix-path payload (structured-sparsity fast lane):
+        # detect N:M structure on the deduped pattern (or honor an explicit
+        # structure_hint) and re-encode the flat tile stream as a packed
+        # payload when the cost model prices it cheaper than the padding waste
+        matrix_format, format_params, nm_payload, bitmap_payload = (
+            _structured_payload(
+                rows, cols, shape, config, cm, flat_values,
+                has_core=bool(part.core_nnz), tile_density=float(tile_density),
+            )
         )
-        worder = plan_r.window_order
-        reuse_factor = plan_r.reuse_factor
-    else:
-        worder = np.arange(nw, dtype=np.int64)
-        reuse_factor = 1.0
-    if st is not None and st.uw.size:
-        # pair p of window w occupies stream position start(w) + slot(p);
-        # nonzeros then land at (their pair's step, row%bm, col%bk) via one
-        # flat scatter-add — no per-window python loop, no value re-gather
-        cnt = num_blocks[worder]
-        total = int(cnt.sum())
-        starts_w = np.zeros(nw, np.int64)
-        starts_w[worder] = np.cumsum(cnt) - cnt
-        step_of_pair = starts_w[st.uw] + st.slot
-        step_window = np.zeros(total, np.int32)
-        step_window[step_of_pair] = st.uw.astype(np.int32)
-        step_col = np.zeros(total, np.int32)
-        step_col[step_of_pair] = st.ub.astype(np.int32)
-        lin = (
-            step_of_pair[st.inv_idx] * config.bm + prow % config.bm
-        ) * config.bk + ccols % config.bk
-        flat = np.zeros(total * config.bm * config.bk, np.float32)
-        np.add.at(flat, lin, part.core_vals.astype(np.float32))
-        flat_values = flat.reshape(total, config.bm, config.bk)
-        core_lin = lin
-    else:  # degenerate all-fringe matrix: one zero tile keeps shapes static
-        step_window = np.zeros(1, np.int32)
-        step_col = np.zeros(1, np.int32)
-        flat_values = np.zeros((1, config.bm, config.bk), np.float32)
-        core_lin = np.zeros(0, np.int64)
 
-    # 3b) structured matrix-path payload (structured-sparsity fast lane):
-    # detect N:M structure on the deduped pattern (or honor an explicit
-    # structure_hint) and re-encode the flat tile stream as a packed
-    # payload when the cost model prices it cheaper than the padding waste
-    matrix_format, format_params, nm_payload, bitmap_payload = (
-        _structured_payload(
-            rows, cols, shape, config, cm, flat_values,
-            has_core=bool(part.core_nnz), tile_density=float(tile_density),
+        # map packed core rows -> original ids
+        core_row_map = np.full(nw * config.bm, -1, np.int64)
+        if n_core:
+            core_row_map[:n_core] = part.core_row_ids[ro.row_order]
+        core_row_map = core_row_map.astype(np.int32)
+
+        # 4) fringe packing: one single-key stable sort (rows are already the
+        # major key, so row runs come out contiguous); packed ids by run scan
+        f_rows, f_cols, f_vals = part.fringe_rows, part.fringe_cols, part.fringe_vals
+        if f_rows.size:
+            order = np.argsort(f_rows * np.int64(k) + f_cols, kind="stable")
+            sr = f_rows[order]
+            first = np.concatenate([[True], sr[1:] != sr[:-1]])
+            fringe_row_ids = sr[first]
+            pr = (np.cumsum(first) - 1).astype(np.int32)
+            pc = f_cols[order].astype(np.int32)
+            # kernels accumulate in fp32; int/f64 input values are cast once
+            # here instead of per-dispatch (and jnp would silently keep ints)
+            pv = f_vals[order].astype(np.float32)
+            fringe_pos = np.empty(order.size, np.int64)
+            fringe_pos[order] = np.arange(order.size)  # fringe entry -> slot
+        else:
+            fringe_row_ids = np.zeros(1, np.int64)
+            pr = np.zeros(1, np.int32)
+            pc = np.zeros(1, np.int32)
+            pv = np.zeros(1, np.float32)
+            fringe_pos = np.zeros(0, np.int64)
+
+        # 4b) vector-path dispatch tier: a VMEM-budget estimate picks the fringe
+        # kernel (resident single-panel / K-sharded streaming / XLA fallback) so
+        # the coordinator's split stays consistent with what the vector engine
+        # can actually execute.  The K-sharded tier consumes the k-bucketed
+        # stream built by plan_ir.bucket_fringe_kblocks; empty k-blocks get no
+        # chunks (their B slices are never fetched).
+        k_pad = ((k + config.bk - 1) // config.bk) * config.bk
+        fringe_tier, fringe_bk = cm.select_fringe_tier(
+            k_pad, int(fringe_row_ids.shape[0]), config.bn,
+            vmem_budget=config.fringe_vmem_budget, nnz=int(f_rows.size),
         )
-    )
+        # 4c) a fringe that runs on XLA is laid out degree-bucketed (ELL): the
+        # executor then gathers and reduces each bucket at a fixed width, with
+        # no per-call sort and no row scatter-add
+        fringe_buckets = ()
+        if f_rows.size and not _shard_part and (
+                fringe_tier == "xla" or config.impl == "xla"):
+            pr, pc, pv, row_order, slot, fringe_buckets = (
+                plan_ir.bucket_fringe_rows(pr, pc, pv))
+            fringe_row_ids = np.where(
+                row_order >= 0, fringe_row_ids[np.maximum(row_order, 0)], -1)
+            fringe_pos = slot[fringe_pos]
+        # the k-bucketed stream is only consumed by the pallas kernels; xla-impl
+        # plans skip the bucketing sort/scatter passes (tier is still recorded)
+        if fringe_tier == "ksharded" and f_rows.size and config.impl != "xla":
+            kb_chunk, kb_rows, kb_cols, kb_vals, kb_pos_of_packed = (
+                plan_ir.bucket_fringe_kblocks(pr, pc, pv, k_pad, fringe_bk)
+            )
+        else:
+            kb_chunk = np.zeros(1, np.int32)
+            kb_rows = np.zeros(1, np.int32)
+            kb_cols = np.zeros(1, np.int32)
+            kb_vals = np.zeros(1, np.float32)
+            kb_pos_of_packed = None
 
-    # map packed core rows -> original ids
-    core_row_map = np.full(nw * config.bm, -1, np.int64)
-    if n_core:
-        core_row_map[:n_core] = part.core_row_ids[ro.row_order]
-    core_row_map = core_row_map.astype(np.int32)
-
-    # 4) fringe packing: one single-key stable sort (rows are already the
-    # major key, so row runs come out contiguous); packed ids by run scan
-    f_rows, f_cols, f_vals = part.fringe_rows, part.fringe_cols, part.fringe_vals
-    if f_rows.size:
-        order = np.argsort(f_rows * np.int64(k) + f_cols, kind="stable")
-        sr = f_rows[order]
-        first = np.concatenate([[True], sr[1:] != sr[:-1]])
-        fringe_row_ids = sr[first]
-        pr = (np.cumsum(first) - 1).astype(np.int32)
-        pc = f_cols[order].astype(np.int32)
-        # kernels accumulate in fp32; int/f64 input values are cast once
-        # here instead of per-dispatch (and jnp would silently keep ints)
-        pv = f_vals[order].astype(np.float32)
-        fringe_pos = np.empty(order.size, np.int64)
-        fringe_pos[order] = np.arange(order.size)  # fringe entry -> slot
-    else:
-        fringe_row_ids = np.zeros(1, np.int64)
-        pr = np.zeros(1, np.int32)
-        pc = np.zeros(1, np.int32)
-        pv = np.zeros(1, np.float32)
-        fringe_pos = np.zeros(0, np.int64)
-
-    # 4b) vector-path dispatch tier: a VMEM-budget estimate picks the fringe
-    # kernel (resident single-panel / K-sharded streaming / XLA fallback) so
-    # the coordinator's split stays consistent with what the vector engine
-    # can actually execute.  The K-sharded tier consumes the k-bucketed
-    # stream built by plan_ir.bucket_fringe_kblocks; empty k-blocks get no
-    # chunks (their B slices are never fetched).
-    k_pad = ((k + config.bk - 1) // config.bk) * config.bk
-    fringe_tier, fringe_bk = cm.select_fringe_tier(
-        k_pad, int(fringe_row_ids.shape[0]), config.bn,
-        vmem_budget=config.fringe_vmem_budget, nnz=int(f_rows.size),
-    )
-    # 4c) a fringe that runs on XLA is laid out degree-bucketed (ELL): the
-    # executor then gathers and reduces each bucket at a fixed width, with
-    # no per-call sort and no row scatter-add
-    fringe_buckets = ()
-    if f_rows.size and not _shard_part and (
-            fringe_tier == "xla" or config.impl == "xla"):
-        pr, pc, pv, row_order, slot, fringe_buckets = (
-            plan_ir.bucket_fringe_rows(pr, pc, pv))
-        fringe_row_ids = np.where(
-            row_order >= 0, fringe_row_ids[np.maximum(row_order, 0)], -1)
-        fringe_pos = slot[fringe_pos]
-    # the k-bucketed stream is only consumed by the pallas kernels; xla-impl
-    # plans skip the bucketing sort/scatter passes (tier is still recorded)
-    if fringe_tier == "ksharded" and f_rows.size and config.impl != "xla":
-        kb_chunk, kb_rows, kb_cols, kb_vals, kb_pos_of_packed = (
-            plan_ir.bucket_fringe_kblocks(pr, pc, pv, k_pad, fringe_bk)
+        # inverse row maps for the scatter-free merge: C's row r gathers from
+        # packed matrix row gather_src_matrix[r] and/or packed fringe row
+        # gather_src_vector[r] (-1 = no contribution from that path)
+        gather_src_matrix = np.full(m, -1, np.int32)
+        valid_slots = np.flatnonzero(core_row_map >= 0)
+        gather_src_matrix[core_row_map[valid_slots]] = valid_slots
+        gather_src_vector = np.full(m, -1, np.int32)
+        if f_rows.size:
+            packed_ids = np.flatnonzero(fringe_row_ids >= 0)
+            gather_src_vector[fringe_row_ids[packed_ids]] = packed_ids
+        update_maps = plan_ir.build_update_maps(
+            rows, cols, vals, shape, part, core_lin, fringe_pos,
+            kb_pos_of_packed,
         )
-    else:
-        kb_chunk = np.zeros(1, np.int32)
-        kb_rows = np.zeros(1, np.int32)
-        kb_cols = np.zeros(1, np.int32)
-        kb_vals = np.zeros(1, np.float32)
-        kb_pos_of_packed = None
-
-    # inverse row maps for the scatter-free merge: C's row r gathers from
-    # packed matrix row gather_src_matrix[r] and/or packed fringe row
-    # gather_src_vector[r] (-1 = no contribution from that path)
-    gather_src_matrix = np.full(m, -1, np.int32)
-    valid_slots = np.flatnonzero(core_row_map >= 0)
-    gather_src_matrix[core_row_map[valid_slots]] = valid_slots
-    gather_src_vector = np.full(m, -1, np.int32)
-    if f_rows.size:
-        packed_ids = np.flatnonzero(fringe_row_ids >= 0)
-        gather_src_vector[fringe_row_ids[packed_ids]] = packed_ids
-    update_maps = plan_ir.build_update_maps(
-        rows, cols, vals, shape, part, core_lin, fringe_pos,
-        kb_pos_of_packed,
-    )
-    t_pack = time.perf_counter() - t0
     stats = (
         ("alpha", float(part.alpha)),
         ("nnz", int(part.nnz)),
@@ -420,9 +426,9 @@ def prepare(
         ("reuse_factor", float(reuse_factor)),
         ("num_windows", int(nw)),
         ("num_steps", int(step_window.shape[0])),
-        ("t_partition_s", t_part),
-        ("t_reorder_s", t_reorder),
-        ("t_pack_s", t_pack),
+        ("t_partition_s", s_part.seconds),
+        ("t_reorder_s", s_reorder.seconds),
+        ("t_pack_s", s_pack.seconds),
         ("k_pad", k_pad),
         ("fringe_tier", fringe_tier),
         ("fringe_bk", int(fringe_bk)),
@@ -437,36 +443,37 @@ def prepare(
         ("padding_waste",
          float(1.0 - tile_density) if part.core_nnz else 0.0),
     )
-    return NeutronPlan(
-        step_window=jnp.asarray(step_window),
-        step_col=jnp.asarray(step_col),
-        flat_values=jnp.asarray(flat_values),
-        core_row_map=jnp.asarray(core_row_map),
-        fringe_rows=jnp.asarray(pr),
-        fringe_cols=jnp.asarray(pc),
-        fringe_vals=jnp.asarray(pv),
-        fringe_row_ids=jnp.asarray(fringe_row_ids.astype(np.int32)),
-        col_perm=jnp.asarray(col_perm.astype(np.int32)),
-        gather_src_matrix=jnp.asarray(gather_src_matrix),
-        gather_src_vector=jnp.asarray(gather_src_vector),
-        fringe_kb_chunk=jnp.asarray(kb_chunk),
-        fringe_kb_rows=jnp.asarray(kb_rows),
-        fringe_kb_cols=jnp.asarray(kb_cols),
-        fringe_kb_vals=jnp.asarray(kb_vals),
-        nm_values=jnp.asarray(nm_payload[0]),
-        nm_codes=jnp.asarray(nm_payload[1]),
-        bitmap_words=jnp.asarray(bitmap_payload[0]),
-        bitmap_values=jnp.asarray(bitmap_payload[1]),
-        shape=tuple(shape),
-        config=config,
-        stats=stats,
-        fringe_tier=fringe_tier,
-        fringe_bk=int(fringe_bk),
-        matrix_format=matrix_format,
-        format_params=tuple(format_params),
-        fringe_buckets=fringe_buckets,
-        update_maps=update_maps,
-    )
+    with span("upload"):
+        return NeutronPlan(
+            step_window=jnp.asarray(step_window),
+            step_col=jnp.asarray(step_col),
+            flat_values=jnp.asarray(flat_values),
+            core_row_map=jnp.asarray(core_row_map),
+            fringe_rows=jnp.asarray(pr),
+            fringe_cols=jnp.asarray(pc),
+            fringe_vals=jnp.asarray(pv),
+            fringe_row_ids=jnp.asarray(fringe_row_ids.astype(np.int32)),
+            col_perm=jnp.asarray(col_perm.astype(np.int32)),
+            gather_src_matrix=jnp.asarray(gather_src_matrix),
+            gather_src_vector=jnp.asarray(gather_src_vector),
+            fringe_kb_chunk=jnp.asarray(kb_chunk),
+            fringe_kb_rows=jnp.asarray(kb_rows),
+            fringe_kb_cols=jnp.asarray(kb_cols),
+            fringe_kb_vals=jnp.asarray(kb_vals),
+            nm_values=jnp.asarray(nm_payload[0]),
+            nm_codes=jnp.asarray(nm_payload[1]),
+            bitmap_words=jnp.asarray(bitmap_payload[0]),
+            bitmap_values=jnp.asarray(bitmap_payload[1]),
+            shape=tuple(shape),
+            config=config,
+            stats=stats,
+            fringe_tier=fringe_tier,
+            fringe_bk=int(fringe_bk),
+            matrix_format=matrix_format,
+            format_params=tuple(format_params),
+            fringe_buckets=fringe_buckets,
+            update_maps=update_maps,
+        )
 
 
 # --- multi-device sharded plan build ----------------------------------------

@@ -168,6 +168,13 @@ def ref_gather_spmm(
 # VMEM (128 MiB on a TPU v5e) across a bucket's gathers, where row gathers
 # run several times faster than from HBM
 GATHER_BLOCK_BYTES = 16 << 20
+# gathered bytes of a whole bucket above which its blocks run in a rolled
+# loop, one block live at a time: unrolled, XLA keeps many blocks of a
+# bucket alive at once (the fused body at ogbn-products' ladder asked for
+# 18.5 GB of the v5e's 15.75 GB), and such a bucket's B rows come from
+# HBM anyway
+ROLL_BUCKET_BYTES = 16 * GATHER_BLOCK_BYTES
+LANES = 128  # the minor tile of a TPU array's layout
 
 
 def bucketed_gather_spmm(
@@ -182,29 +189,52 @@ def bucketed_gather_spmm(
     width-major (``plan_ir.bucket_fringe_rows`` lays it out): reshaped to
     ``(width_b, n_rows_b)``, row ``j`` holds width position ``j`` of every
     row of the bucket.  Each bucket gathers its B rows a block of width
-    positions at a time (at most ``GATHER_BLOCK_BYTES`` per gather),
-    multiplies by its values in fp32 and sums over the width; the buckets
-    concatenate to the packed ``(sum n_rows_b, N)`` output.  The same fp32
-    products as :func:`ref_gather_spmm`, summed per row in another order;
-    padding slots add exact zeros for finite B.
+    positions at a time (at most ``GATHER_BLOCK_BYTES`` per gather, or one
+    position where a single one is larger), multiplies by its values in
+    fp32 and sums over the width; the buckets concatenate to the packed
+    ``(sum n_rows_b, N)`` output.  A bucket whose gathers total more than
+    ``ROLL_BUCKET_BYTES`` runs its blocks in a ``fori_loop`` (a
+    power-of-two block that divides the width), so device memory holds one
+    block.  The same fp32 products as :func:`ref_gather_spmm`, summed per
+    row in another order; padding slots add exact zeros for finite B.
 
     On a TPU v5e at ogbn-arxiv's ladder (width 128) the whole product ran
     5.91 ms per call this way, against 7.29 ms with whole-bucket gathers
-    (B evicted to HBM for some) and 20.50 ms with the segment sum.
+    (B evicted to HBM for some) and 20.50 ms with the segment sum; at
+    ogbn-products' ladder (B 1.25 GB, in HBM) 625 ms.
     """
     outs = []
     start = 0
     row_bytes = b.shape[1] * 4
     for n_rows, width in buckets:
         end = start + n_rows * width
-        c = cols[start:end].reshape(width, n_rows)
-        v = vals[start:end].reshape(width, n_rows).astype(jnp.float32)
+        c, v = cols[start:end], vals[start:end]
+        if n_rows < LANES:
+            # a (width, n_rows) view pads its rows to the lane tile; XLA
+            # would hoist that reshape over the whole stream (16x of
+            # ogbn-products' 86M slots at n_rows 8) unless the slice stays
+            c, v = jax.lax.optimization_barrier((c, v))
+        c = c.reshape(width, n_rows)
+        v = v.reshape(width, n_rows).astype(jnp.float32)
         step = max(1, GATHER_BLOCK_BYTES // (n_rows * row_bytes))
-        acc = None
-        for j in range(0, width, step):
-            part = jnp.sum(b[c[j:j + step]].astype(jnp.float32)
-                           * v[j:j + step, :, None], axis=0)
-            acc = part if acc is None else acc + part
+
+        def block(cj, vj):
+            return jnp.sum(b[cj].astype(jnp.float32) * vj[:, :, None],
+                           axis=0)
+
+        if n_rows * width * row_bytes > ROLL_BUCKET_BYTES:
+            step = 1 << (step.bit_length() - 1)
+            cs = c.reshape(width // step, step, n_rows)
+            vs = v.reshape(width // step, step, n_rows)
+            acc = jax.lax.fori_loop(
+                0, width // step,
+                lambda i, acc: acc + block(cs[i], vs[i]),
+                jnp.zeros((n_rows, b.shape[1]), jnp.float32))
+        else:
+            acc = None
+            for j in range(0, width, step):
+                part = block(c[j:j + step], v[j:j + step])
+                acc = part if acc is None else acc + part
         outs.append(acc)
         start = end
     return jnp.concatenate(outs)

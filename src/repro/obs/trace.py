@@ -222,17 +222,19 @@ class span:
     """``with span("launch"):`` -- the program's host phase span.
 
     Opens ``repro.<name>`` on the profiler's trace and records its host
-    duration in :data:`SPAN_TIMES`.  :meth:`close` ends it early (the
-    dispatch path closes ``lookup`` right before it launches); the
-    ``with`` block's own exit then does nothing.
+    duration in :data:`SPAN_TIMES`; once closed, :attr:`seconds` holds
+    the same duration.  :meth:`close` ends it early (the dispatch path
+    closes ``lookup`` right before it launches); the ``with`` block's own
+    exit then does nothing.
     """
 
-    __slots__ = ("name", "_annotation", "_t0")
+    __slots__ = ("name", "_annotation", "_t0", "_ns")
 
     def __init__(self, name: str):
         self.name = name
         self._annotation = TraceAnnotation(SPAN_PREFIX + name)
         self._t0 = 0
+        self._ns = 0
 
     def __enter__(self) -> "span":
         self._annotation.__enter__()
@@ -246,9 +248,15 @@ class span:
     def closed(self) -> bool:
         return self._annotation is None
 
+    @property
+    def seconds(self) -> float:
+        """The recorded host duration (0 while the span is open)."""
+        return self._ns * 1e-9
+
     def close(self) -> None:
         if self._annotation is None:
             return
-        SPAN_TIMES.record(self.name, time.perf_counter_ns() - self._t0)
+        self._ns = time.perf_counter_ns() - self._t0
+        SPAN_TIMES.record(self.name, self._ns)
         self._annotation.__exit__(None, None, None)
         self._annotation = None

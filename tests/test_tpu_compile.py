@@ -9,7 +9,8 @@ Nothing is executed; a passing compile is not a chip run.
 Shapes are the widths ``chip_smoke.py`` runs at — the ogbn-arxiv-scale
 graph (169,343 nodes, feature width 128), the ``wiki-RfA`` / ``ogbn-arxiv``
 stand-ins and ``dlmc-nm-1-32`` at width 256 — plus fringes of 2^20
-nonzeros, past the ~80k-nonzero ceiling a wholly prefetched stream hit.
+nonzeros, past the ~80k-nonzero ceiling a wholly prefetched stream hit,
+and the benchmark's ogbn-products plan (width 100, 2.45M rows).
 """
 import os
 
@@ -35,6 +36,19 @@ ARXIV_STEPS = 2_646        # tile steps of its matrix path (bm=128, bk=64)
 ARXIV_LADDER = ((18_368, 1), (62_280, 2), (43_016, 4), (24_440, 8),
                 (11_888, 16), (5_488, 32), (2_304, 64), (920, 128),
                 (376, 256), (168, 512), (72, 1_024))
+
+# ogbn-products (chipbench/configs/ogbn-products.json) as prepare lays it
+# out: operand width 100, PRODUCTS_STEPS tile steps, and an XLA-tier
+# fringe of 86,132,352 slots in 11 buckets whose B panel (1.25 GB padded)
+# lives in HBM; unrolled, the large buckets' gathers did not fit the
+# v5e's HBM
+PRODUCTS_K = 2_449_088     # 2,449,029 padded to the bk=64 multiple
+PRODUCTS_WIDTH = 100
+PRODUCTS_STEPS = 22_959
+PRODUCTS_LADDER = ((1_036_296, 16), (1_039_408, 32), (277_376, 64),
+                   (71_824, 128), (18_072, 256), (4_512, 512),
+                   (1_200, 1_024), (288, 2_048), (64, 4_096), (24, 8_192),
+                   (8, 16_384))
 
 
 @pytest.fixture(scope="module")
@@ -119,6 +133,13 @@ CASES = {
                 ((2048, 128, 8), F32), ((4096, 256), F32)],
         static=dict(num_windows=32, bm=128, bk=64, bn=256, row_cap=8),
     ),
+    # ogbn-products' matrix path: its tile steps over a 2.45M-row panel
+    "dense_tile_spmm-products": dict(
+        fn=dense_tile_spmm,
+        shapes=[((PRODUCTS_STEPS,), I32), ((PRODUCTS_STEPS,), I32),
+                ((PRODUCTS_STEPS, 128, 64), F32), ((PRODUCTS_K, 128), F32)],
+        static=dict(num_windows=1, bm=128, bk=64, bn=128),
+    ),
     # phase a sddmm matrix path: one window panel against Y's k-blocks
     "dense_tile_sddmm-arxiv": dict(
         fn=dense_tile_sddmm,
@@ -182,20 +203,30 @@ def test_fused_body_scopes_name_the_tpu_kernels(one_chip):
     assert "/fringe_path/" in scope_of["gather_spmm_ksharded"], kernels
 
 
-def test_bucketed_fringe_compiles_without_sort_or_scatter(one_chip):
-    """The XLA fringe at ogbn-arxiv's bucket ladder and width 128, as the
-    fused body runs it: gathers and reductions only, no sort and no
-    scatter (the unbucketed stream compiles to both)."""
+@pytest.mark.parametrize("k, width, ladder", [
+    (ARXIV_K, 128, ARXIV_LADDER),
+    (PRODUCTS_K, PRODUCTS_WIDTH, PRODUCTS_LADDER),
+], ids=["ogbn-arxiv", "ogbn-products"])
+def test_bucketed_fringe_compiles_without_sort_or_scatter(one_chip, k, width,
+                                                          ladder):
+    """The XLA fringe at a configuration's bucket ladder and operand width,
+    as the fused body runs it (B padded to the 128 lanes of ``bn``):
+    gathers and reductions only, no sort and no scatter (the unbucketed
+    stream compiles to both), within the chip's HBM."""
     from repro.kernels import ops
 
-    rows = sum(n for n, _ in ARXIV_LADDER)
-    slots = sum(n * w for n, w in ARXIV_LADDER)
+    rows = sum(n for n, _ in ladder)
+    slots = sum(n * w for n, w in ladder)
+
+    @jax.jit
+    def fringe(r, c, v, b):
+        bp = jnp.pad(b, ((0, 0), (0, 128 - width)))
+        return ops.fringe_spmm(r, c, v, bp, num_rows=rows, bn=128,
+                               impl="pallas", tier="xla",
+                               buckets=ladder)[:, :width]
+
     args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in (
-        ((slots,), I32), ((slots,), I32), ((slots,), F32),
-        ((ARXIV_K, 128), F32))]
-    compiled = ops.fringe_spmm.lower(
-        *args, num_rows=rows, bn=128, impl="pallas", tier="xla",
-        buckets=ARXIV_LADDER).compile()
-    text = compiled.as_text()
+        ((slots,), I32), ((slots,), I32), ((slots,), F32), ((k, width), F32))]
+    text = fringe.lower(*args).compile().as_text()
     assert " gather(" in text
     assert " sort(" not in text and " scatter(" not in text
