@@ -756,28 +756,32 @@ class SddmmMaps:
 
     SDDMM inverts the SpMM dataflow: the matrix engine computes dense
     ``X_window @ Y_kblock`` tiles for exactly the (window, k-block) pairs the
-    plan's tile stream names, and per-nonzero values are *extracted* from the
-    flat tile stream at the same linear slots ``prepare()`` scattered values
-    into (``UpdateMaps.core_lin``).  Fringe nonzeros bypass the tile path and
-    compute their dot products by row gather.  Output order is the plan's
-    original COO input order — layout-compatible with
+    plan's tile stream names, and each core nonzero's value is *extracted*
+    from the tile stream at the linear slot ``prepare()`` scattered its
+    value into (``UpdateMaps.core_lin``).  Fringe nonzeros bypass the tile
+    path and compute their dot products by row gather.  Output order is the
+    plan's original COO input order — layout-compatible with
     ``dynamic.update_values(plan, arange(nnz), out)``.
 
-    Extraction (unlike accumulation) is duplicate-safe: duplicate COO
-    triplets share a tile slot but read the same dot product.
+    The merge reads each path's values once, where that path wrote them:
+    ``c_lin`` names only the core nonzeros' slots, and ``place`` — fixed
+    per pattern, a permutation of ``0..nnz-1`` — gives the COO position of
+    each value of ``[core values..., fringe values...]``, both subsets in
+    COO order.  Extraction (unlike accumulation) is duplicate-safe:
+    duplicate COO triplets share a tile slot but read the same dot product.
     """
 
     g_rows: jax.Array    # (nnz,) int32 original rows, every nonzero
     g_cols: jax.Array    # (nnz,) int32 original cols, every nonzero
-    core_lin: jax.Array  # (nnz,) int32 flat tile slot, -1 on the fringe path
-    f_idx: jax.Array     # (nnz,) int32 index into the fringe subset, -1 core
+    c_lin: jax.Array     # (n_core,) int32 flat tile slot of each core nonzero
+    place: jax.Array     # (nnz,) int32 COO position of core, then fringe
     f_rows: jax.Array    # (nnz_f,) int32 fringe-subset rows (>=1, padded)
     f_cols: jax.Array    # (nnz_f,) int32 fringe-subset cols
     nnz: int
     nnz_f: int           # padded fringe-subset length
 
     def leaves(self) -> Tuple[jax.Array, ...]:
-        return (self.g_rows, self.g_cols, self.core_lin, self.f_idx,
+        return (self.g_rows, self.g_cols, self.c_lin, self.place,
                 self.f_rows, self.f_cols)
 
 
@@ -810,19 +814,18 @@ def build_sddmm_maps(plan: NeutronPlan) -> SddmmMaps:
     if cached is not None:
         return cached
     core = maps.core_lin >= 0
-    f_sel = np.flatnonzero(~core)
-    f_idx = np.full(maps.nnz, -1, np.int64)
-    f_idx[f_sel] = np.arange(f_sel.size)
-    f_rows = maps.rows[f_sel]
-    f_cols = maps.cols[f_sel]
+    c_pos = np.flatnonzero(core)
+    f_pos = np.flatnonzero(~core)
+    f_rows = maps.rows[f_pos]
+    f_cols = maps.cols[f_pos]
     if f_rows.size == 0:  # keep the gather operand nonempty for the kernels
         f_rows = np.zeros(1, np.int64)
         f_cols = np.zeros(1, np.int64)
     built = SddmmMaps(
         g_rows=jnp.asarray(maps.rows, jnp.int32),
         g_cols=jnp.asarray(maps.cols, jnp.int32),
-        core_lin=jnp.asarray(maps.core_lin, jnp.int32),
-        f_idx=jnp.asarray(f_idx, jnp.int32),
+        c_lin=jnp.asarray(maps.core_lin[c_pos], jnp.int32),
+        place=jnp.asarray(np.concatenate([c_pos, f_pos]), jnp.int32),
         f_rows=jnp.asarray(f_rows, jnp.int32),
         f_cols=jnp.asarray(f_cols, jnp.int32),
         nnz=maps.nnz, nnz_f=int(f_rows.shape[0]),

@@ -25,10 +25,11 @@ Every body stage runs under one of the ``jax.named_scope`` names in
 :data:`SCOPES`: ``b_prep`` (the operand permutation, padding and
 relayout), ``matrix_path`` (the tile stream), ``fringe_path`` (the
 per-nonzero gather kernels) and ``merge`` (the gathers of both paths into
-the output's order, and their sum).  A scope is compile-time metadata,
-the ``op_name`` of each HLO operation: it costs nothing at run time and
-keys no cache, and on a profiler trace it names the stage each device
-operation belongs to.
+the output's order, and their sum; for sddmm, the core values' gather and
+the sort that places both paths' values in COO order).  A scope is
+compile-time metadata, the ``op_name`` of each HLO operation: it costs
+nothing at run time and keys no cache, and on a profiler trace it names
+the stage each device operation belongs to.
 """
 from __future__ import annotations
 
@@ -37,6 +38,7 @@ from typing import Any, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from ..core.plan_ir import (
     DELTA_LEAF_RANKS, LEAF_COL_PERM, LEAF_RANKS, N_DELTA_LEAVES,
@@ -68,6 +70,13 @@ _FRINGE_FORMULATION = REGISTRY.counter(
     "exec_fringe_formulation_total",
     "fused-body traces with a fringe, by the fringe stream's formulation",
     labelnames=("formulation",))
+# "sorted": both paths' values placed into COO order by one key-value sort;
+# "core_only" / "fringe_only": one path's values are already in COO order;
+# "reference": the one gather over every nonzero (impl "xla", degrade target)
+_SDDMM_MERGE = REGISTRY.counter(
+    "exec_sddmm_merge_total",
+    "sddmm fused-body traces, by how the merge places values in COO order",
+    labelnames=("placement",))
 
 
 def _fused_body(sig: Tuple, densify_occupancy: Optional[float] = None):
@@ -162,8 +171,8 @@ def _sddmm_body(sig: Tuple):
 
     Inverts the SpMM dataflow on the same plan structure: the matrix engine
     computes dense ``X_window @ Y_kblock`` products for exactly the tiles
-    the plan's stream names and per-nonzero values are *extracted* at the
-    plan's ``core_lin`` slots; fringe nonzeros gather one X row and one Y
+    the plan's stream names and each core nonzero's value is *extracted*
+    at its ``c_lin`` slot; fringe nonzeros gather one X row and one Y
     column each on the vector engine.  Output is (nnz,) fp32 in the plan's
     original COO input order — feed it straight to
     ``dynamic.update_values(plan, arange(nnz), out)``.
@@ -179,7 +188,7 @@ def _sddmm_body(sig: Tuple):
     _nnz, _nnz_fs, vmem_budget = op_extra(sig)
 
     def _run(step_window, step_col, core_row_map, col_perm,
-             g_rows, g_cols, core_lin, f_idx, f_rows, f_cols, x, y):
+             g_rows, g_cols, c_lin, place, f_rows, f_cols, x, y):
         record_fused_trace(sig)
         if impl != "xla":  # pallas tiers lower here, at trace time
             HARNESS.fire("pallas_lowering", context=sig)
@@ -188,11 +197,12 @@ def _sddmm_body(sig: Tuple):
         if impl == "xla" or not (has_core or has_fringe):
             # reference gather over every nonzero — also the complete
             # degrade target xla_fallback_sig demotes pallas failures to
+            _SDDMM_MERGE.inc(placement="reference")
             with jax.named_scope(FRINGE_PATH):
                 return ops.sddmm_gather(
                     g_rows, g_cols, x, yt, impl="xla", chunk=fringe_chunk,
                 )
-        tiles = fv = None
+        core_vals = fv = None
         if has_core:
             # matrix path: window-gathered X rows x column-permuted Y panel
             with jax.named_scope(B_PREP):
@@ -208,24 +218,30 @@ def _sddmm_body(sig: Tuple):
                 tiles = ops.sddmm_block_stream(
                     step_window, step_col, xp, yp, bm=bm, bk=bk, impl=impl,
                 )
+            # only the core nonzeros' slots, addressed in the (T, bm, bk)
+            # layout the kernel wrote: a flat view would relayout the stream
+            with jax.named_scope(MERGE):
+                core_vals = tiles[c_lin // (bm * bk), (c_lin // bk) % bm,
+                                  c_lin % bk]
         if has_fringe:
             with jax.named_scope(FRINGE_PATH):
                 fv = ops.sddmm_gather(
                     f_rows, f_cols, x, yt, impl=impl, chunk=fringe_chunk,
                     vmem_budget=vmem_budget,
                 )
-        # merge: both paths' values back into the plan's COO input order
+        # one path alone is already in COO order; both are placed there by
+        # sorting the values on their fixed COO positions (the keys are
+        # unique, so an unstable sort places them alike and compiles faster)
+        if fv is None:
+            _SDDMM_MERGE.inc(placement="core_only")
+            return core_vals
+        if core_vals is None:
+            _SDDMM_MERGE.inc(placement="fringe_only")
+            return fv
+        _SDDMM_MERGE.inc(placement="sorted")
         with jax.named_scope(MERGE):
-            core_vals = fringe_vals = None
-            if tiles is not None:
-                core_vals = tiles.reshape(-1)[jnp.clip(core_lin, 0)]
-            if fv is not None:
-                fringe_vals = fv[jnp.clip(f_idx, 0)]
-            if core_vals is None:
-                return fringe_vals
-            if fringe_vals is None:
-                return core_vals
-            return jnp.where(core_lin >= 0, core_vals, fringe_vals)
+            return lax.sort((place, jnp.concatenate([core_vals, fv])),
+                            num_keys=1, is_stable=False)[1]
 
     return _run
 

@@ -342,7 +342,7 @@ def sddmm_block_stream(
     """SDDMM matrix-engine path; returns the fp32 tile stream (T, bm, bk).
 
     ``xp`` is the window-gathered X row panel (num_windows*bm, D) and
-    ``yp`` the column-permuted, K-padded Y operand (D, K).  Per-nonzero
+    ``yp`` the column-permuted, K-padded Y operand (D, K).  Core nonzeros'
     values are extracted from the returned stream at the plan's
     ``UpdateMaps.core_lin`` slots — the same linear addressing prepare()
     scattered input values under, so extraction needs no new metadata.

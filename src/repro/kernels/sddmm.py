@@ -14,10 +14,11 @@ row panel and the Y column panel:
   Y panel  : Yp^T[c[t]*bk : , :]     (bk, D)    VMEM, streamed per step
   out tile : tiles[t]                (bm, bk)   fp32
 
-The caller extracts per-nonzero values from the flat (T, bm, bk) stream at
-the plan's ``UpdateMaps.core_lin`` slots — the exact linear slots
-``prepare()`` scattered values into, so the result is layout-compatible
-with ``dynamic.update_values``.
+The caller extracts the core nonzeros' values from the (T, bm, bk) stream
+at their ``UpdateMaps.core_lin`` slots — the exact linear slots
+``prepare()`` scattered values into — and places them, with the fringe's,
+in COO input order, so the result is layout-compatible with
+``dynamic.update_values``.
 
 ``gather_sddmm`` (vector engine) — fringe nonzeros bypass the tile path;
 each computes one dot product by gathering a row of X and a row of Y^T:

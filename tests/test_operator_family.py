@@ -21,7 +21,9 @@ from repro.errors import PlanBuildError
 from repro.exec import (
     dispatch_count, execute_sddmm, execute_spspmm, fused_trace_count,
 )
+from repro.kernels import ops
 from repro.launch.mesh import make_spmm_mesh
+from repro.obs import REGISTRY
 import repro.sparse as sp
 from conftest import make_sparse
 
@@ -152,6 +154,124 @@ def test_sddmm_empty_and_single_path_plans(rng):
         plan = spmm.prepare(rows, cols, vals, (48, 40),
                             spmm.SpmmConfig(impl="xla", alpha=alpha))
         _check(execute_sddmm(plan, jnp.asarray(x), jnp.asarray(y)), expect)
+
+
+def _old_merge(plan, x, y):
+    """The merge the path-ordered one replaced, kept as the oracle: one
+    gather per nonzero from the flat tile stream and one from the fringe
+    values, then a select on the path."""
+    cfg, maps = plan.config, plan.update_maps
+    k = plan.shape[1]
+    core_lin = maps.core_lin
+    f_sel = np.flatnonzero(core_lin < 0)
+    f_idx = np.full(maps.nnz, -1)
+    f_idx[f_sel] = np.arange(f_sel.size)
+    core_vals = fringe_vals = None
+    if plan.has_core:
+        crm = plan.core_row_map
+        xp = jnp.where((crm >= 0)[:, None],
+                       x[jnp.clip(crm, 0, x.shape[0] - 1)], 0.0)
+        yp = y[:, plan.col_perm] if cfg.reorder_cols else y
+        yp = jnp.pad(yp, ((0, 0), (0, -k % cfg.bk)))
+        tiles = ops.sddmm_block_stream(plan.step_window, plan.step_col, xp,
+                                       yp, bm=cfg.bm, bk=cfg.bk,
+                                       impl=cfg.impl)
+        core_vals = np.asarray(tiles).reshape(-1)[np.clip(core_lin, 0, None)]
+    if plan.has_fringe:
+        fv = ops.sddmm_gather(
+            jnp.asarray(maps.rows[f_sel], jnp.int32),
+            jnp.asarray(maps.cols[f_sel], jnp.int32), x, y.T,
+            impl=cfg.impl, chunk=cfg.fringe_chunk,
+            vmem_budget=cfg.fringe_vmem_budget)
+        fringe_vals = np.asarray(fv)[np.clip(f_idx, 0, None)]
+    if core_vals is None:
+        return fringe_vals
+    if fringe_vals is None:
+        return core_vals
+    return np.where(core_lin >= 0, core_vals, fringe_vals)
+
+
+def _merge_case(rng, case):
+    """(rows, cols, SpmmConfig overrides, batch) of one merge case."""
+    _, rows, cols, _ = make_sparse(rng, 96, 80, 0.07, n_dense_rows=4)
+    if case == "duplicates":
+        dup = rng.choice(rows.size, 40, replace=False)
+        rows = np.concatenate([rows, rows[dup]])
+        cols = np.concatenate([cols, cols[dup]])
+    if case != "row_sorted":  # COO in shuffled order, not row-sorted
+        order = rng.permutation(rows.size)
+        rows, cols = rows[order], cols[order]
+    # alpha 0.5 sends the sparse tail to the fringe: both paths carry work
+    over = {"core_only": dict(alpha=0.0), "fringe_only": dict(alpha=1.0),
+            "reorder_cols": dict(alpha=0.5, reorder_cols=True),
+            "xla_fringe_tier": dict(alpha=0.5, fringe_vmem_budget=16),
+            }.get(case, dict(alpha=0.5))
+    return rows, cols, over, 3 if case == "batched" else None
+
+
+@pytest.mark.parametrize("case", [
+    "row_sorted", "shuffled", "duplicates", "core_only", "fringe_only",
+    "reorder_cols", "batched", "xla_fringe_tier"])
+def test_sddmm_path_ordered_merge_bit_identical_to_old_merge(rng, case):
+    """The path-ordered merge (compact core gather, then one key-value
+    sort into COO order) places the very values the per-nonzero gathers
+    did, bit for bit, and they match ``X @ Y`` at the pattern.  Every case
+    runs the Pallas tile stream in interpret mode: impl "xla" takes the
+    reference gather and never reaches the merge."""
+    rows, cols, over, batch = _merge_case(rng, case)
+    m, k, d = 96, 80, 12
+    plan = spmm.prepare(rows, cols, np.ones(rows.size), (m, k),
+                        spmm.SpmmConfig(impl="pallas_interpret", bn=BN,
+                                        **over))
+    assert (plan.has_core, plan.has_fringe) == {
+        "core_only": (True, False), "fringe_only": (False, True),
+    }.get(case, (True, True))
+    if case == "xla_fringe_tier":
+        assert plan.fringe_tier == "xla"
+    place = np.asarray(plan_ir.build_sddmm_maps(plan).place)
+    np.testing.assert_array_equal(np.sort(place), np.arange(rows.size))
+    lead = () if batch is None else (batch,)
+    x = jnp.asarray(rng.randn(*lead, m, d).astype(np.float32))
+    y = jnp.asarray(rng.randn(*lead, d, k).astype(np.float32))
+    out = np.asarray(execute_sddmm(plan, x, y))
+    for i in range(batch or 1):
+        xi, yi = (x, y) if batch is None else (x[i], y[i])
+        oi = out if batch is None else out[i]
+        np.testing.assert_array_equal(oi, _old_merge(plan, xi, yi))
+        _check(oi, (np.asarray(xi, np.float64)
+                    @ np.asarray(yi, np.float64))[rows, cols])
+
+
+def _merge_placements():
+    snap = REGISTRY.snapshot().get("exec_sddmm_merge_total", {})
+    return {s["labels"]["placement"]: s["value"]
+            for s in snap.get("series", ())}
+
+
+@pytest.mark.parametrize("impl,alpha,placement", [
+    ("pallas_interpret", 0.5, "sorted"),
+    ("pallas_interpret", 0.0, "core_only"),
+    ("pallas_interpret", 1.0, "fringe_only"),
+    ("xla", None, "reference"),
+])
+def test_sddmm_merge_counter(rng, impl, alpha, placement):
+    """One count per fused-body trace, under the placement it traced, and
+    none on a warm call; a width of its own per case forces a fresh
+    trace."""
+    a, rows, cols, vals = make_sparse(rng, 96, 80, 0.07, n_dense_rows=4)
+    plan = spmm.prepare(rows, cols, vals, a.shape,
+                        spmm.SpmmConfig(impl=impl, bn=BN, alpha=alpha))
+    d = {"sorted": 19, "core_only": 21, "fringe_only": 23,
+         "reference": 25}[placement]
+    x = jnp.asarray(rng.randn(96, d).astype(np.float32))
+    y = jnp.asarray(rng.randn(d, 80).astype(np.float32))
+    before = _merge_placements()
+    execute_sddmm(plan, x, y).block_until_ready()
+    after = _merge_placements()
+    grew = {p: after[p] - before.get(p, 0) for p in after}
+    assert {p: n for p, n in grew.items() if n} == {placement: 1}
+    execute_sddmm(plan, x, y).block_until_ready()
+    assert _merge_placements() == after
 
 
 def test_sddmm_operand_validation(rng):

@@ -37,6 +37,12 @@ ARXIV_LADDER = ((18_368, 1), (62_280, 2), (43_016, 4), (24_440, 8),
                 (11_888, 16), (5_488, 32), (2_304, 64), (920, 128),
                 (376, 256), (168, 512), (72, 1_024))
 
+# its sddmm plan: nonzeros on the tile stream and on the fringe
+ARXIV_NNZ = 1_165_769
+ARXIV_CORE_NNZ = 114_599
+ARXIV_FRINGE_NNZ = 1_051_170
+ARXIV_CORE_ROWS = 128      # one window of X rows: 2,646 steps, one per k-block
+
 # ogbn-products (chipbench/configs/ogbn-products.json) as prepare lays it
 # out: operand width 100, PRODUCTS_STEPS tile steps, and an XLA-tier
 # fringe of 86,132,352 slots in 11 buckets whose B panel (1.25 GB padded)
@@ -230,3 +236,46 @@ def test_bucketed_fringe_compiles_without_sort_or_scatter(one_chip, k, width,
     text = fringe.lower(*args).compile().as_text()
     assert " gather(" in text
     assert " sort(" not in text and " scatter(" not in text
+
+
+def test_sddmm_merge_compiles_without_full_length_gathers(one_chip):
+    """The sddmm fused body at ogbn-arxiv's plan, compiled for a v5e: the
+    merge gathers only the core nonzeros out of the (T, bm, bk) tile
+    stream, in the layout the kernel wrote it (no flat relayout of the
+    stream), and places both paths' values by one sort; no gather
+    produces a value per nonzero."""
+    import re
+
+    import numpy as np
+
+    from repro.core import plan_ir, spmm
+    from repro.exec.pipeline import build_executor
+
+    # a small mixed plan supplies the signature; the shapes are arxiv's
+    rng = np.random.default_rng(0)
+    rows, cols = rng.integers(0, 300, 3000), rng.integers(0, 300, 3000)
+    rows[:600] = 5  # one dense row for the tile stream
+    plan = spmm.prepare(rows, cols, np.ones(3000), (300, 300),
+                        spmm.SpmmConfig(impl="pallas", bn=128, alpha=0.5))
+    assert plan.has_core and plan.has_fringe
+    sig = list(plan.signature())
+    sig[1] = (ARXIV_K - 1, ARXIV_K - 1)
+    sig = plan_ir.tag_op(tuple(sig), "sddmm", ARXIV_NNZ, ARXIV_FRINGE_NNZ,
+                         None)
+    n, nc, nf = ARXIV_NNZ, ARXIV_CORE_NNZ, ARXIV_FRINGE_NNZ
+    shapes = [(ARXIV_STEPS,), (ARXIV_STEPS,), (ARXIV_CORE_ROWS,),
+              (ARXIV_K - 1,), (n,), (n,), (nc,), (n,), (nf,), (nf,)]
+    args = [jax.ShapeDtypeStruct(s, I32, sharding=one_chip) for s in shapes]
+    args += [jax.ShapeDtypeStruct(s, F32, sharding=one_chip)
+             for s in ((ARXIV_K - 1, 128), (128, ARXIV_K - 1))]
+    text = build_executor(sig).lower(*args).compile().as_text()
+    assert "dense_tile_sddmm" in text
+    gathers = [line.split(" gather(")[0] for line in text.splitlines()
+               if " gather(" in line]
+    assert not [g for g in gathers if f"f32[{n}]" in g], gathers
+    assert [g for g in gathers if f"f32[{nc}]" in g], gathers
+    # the core gather reads the stream as the kernel wrote it, unflattened
+    stream = re.escape(f"f32[{ARXIV_STEPS},128,64]")
+    assert re.search(rf"\(param[^)]*: {stream}[^)]*\) -> f32\[{nc}\]", text)
+    assert f"f32[{ARXIV_STEPS * 128 * 64}]" not in text
+    assert " sort(" in text
