@@ -16,7 +16,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import spmm
-from repro.launch.mesh import make_spmm_mesh
+from repro.distributed.sharding import make_spmm_mesh
 
 from .common import emit, load_dataset, time_fn
 

@@ -22,8 +22,8 @@ import numpy as np
 
 from repro.compile_cache import enable_compile_cache
 from repro.core import spmm
+from repro.distributed.sharding import make_spmm_mesh
 from repro.hostdevices import force_host_device_count
-from repro.launch.mesh import make_spmm_mesh
 
 from .common import geomean, load_dataset, time_fn
 

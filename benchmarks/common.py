@@ -3,8 +3,7 @@
 Every bench prints ``name,us_per_call,derived`` rows (one per paper
 table/figure cell).  Wall-clock numbers are CPU-host timings of the XLA
 paths — meaningful as *relative* comparisons that exercise the framework's
-coordination logic; kernel-level TPU projections live in the roofline
-artifacts (benchmarks/bench_roofline.py).
+coordination logic; chip timings come from ``chipbench/``.
 """
 from __future__ import annotations
 

@@ -19,7 +19,6 @@ SUITES = [
     "bench_scaling_n",      # Fig. 23
     "bench_tile_redundancy",  # Table 1
     "bench_preprocess",     # Tables 3/4
-    "bench_roofline",       # EXPERIMENTS.md §Roofline feed
     "bench_fused",          # fused single-dispatch executor vs two-dispatch
     "bench_sharded",        # multi-device sharded executor scaling
     "bench_dynamic",        # dynamic updates vs full re-prepare

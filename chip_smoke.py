@@ -327,7 +327,7 @@ def four_chip_phase(impl, seed, arxiv_nodes=ARXIV_NODES, n_shards=4):
     import repro.sparse as sp
     from repro.core.plan_ir import SpmmConfig
     from repro.core.spmm import prepare_sharded
-    from repro.launch.mesh import make_spmm_mesh
+    from repro.distributed.sharding import make_spmm_mesh
 
     rng = np.random.default_rng(seed)
     cfg = SpmmConfig(impl=impl, degrade_to_xla=False, seed=seed, bn=FEATURES)

@@ -25,8 +25,9 @@ TPU adaptation
   measured throughputs when ``measure`` calibration is used.
 
 Two calibration modes:
-- ``analytic_tpu``: derive rates from roofline constants (used by the
-  dry-run / roofline pipeline where wall-clock is meaningless on CPU).
+- ``analytic_tpu``: derive rates from roofline constants (the default,
+  ``default_cost_model``; no timing, so it also holds where wall-clock
+  is meaningless, as on CPU).
 - ``measure``: time the two jitted paths on the current backend (used by
   the runtime coordinator, mirroring the paper's microbenchmark dry run).
 """

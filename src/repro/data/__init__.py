@@ -1,4 +1,4 @@
-"""Data: deterministic synthetic pipeline + sparse-matrix generators."""
-from . import graphs, pipeline
+"""Data: deterministic sparse-matrix generators."""
+from . import graphs
 
-__all__ = ["graphs", "pipeline"]
+__all__ = ["graphs"]

@@ -42,6 +42,7 @@ import numpy as np
 
 from ..checkpoint import checkpoint
 from ..core import spmm
+from ..distributed.sharding import make_spmm_mesh
 # RegistryError lives in the shared taxonomy (repro.errors) and is
 # re-exported here for the historical import path
 from ..errors import RegistryError  # noqa: F401
@@ -399,8 +400,6 @@ class PlanRegistry:
                 f"a plan: {e}"
             ) from e
         if mesh is None:
-            from ..launch.mesh import make_spmm_mesh
-
             try:
                 mesh = make_spmm_mesh(n_shards, axis_name)
             except ValueError as e:

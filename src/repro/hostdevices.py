@@ -4,10 +4,9 @@ The CPU device count is fixed when jax creates its backend, so multi-device
 CPU coverage requires ``XLA_FLAGS=--xla_force_host_platform_device_count=N``
 in the environment before the first device query.  This module stays
 importable without touching jax — the CPU tools call it first thing in
-``main`` (benchmarks/collect_sharded_json.py, launch/dryrun.py,
-launch/perf.py), and it builds subprocess environments (the
-``forced_mesh_run`` fixture).  Nothing on the library's import path calls
-it: on a chip the platform is never forced.
+``main`` (benchmarks/collect_sharded_json.py), and it builds subprocess
+environments (the ``forced_mesh_run`` fixture).  Nothing on the
+library's import path calls it: on a chip the platform is never forced.
 """
 from __future__ import annotations
 

@@ -4,8 +4,9 @@
 - ``pallas``           — Mosaic-lowered TPU kernels (target hardware)
 - ``pallas_interpret`` — same kernel bodies executed in interpret mode
                          (CPU-validatable; used by tests/benchmarks here)
-- ``xla``              — pure-jnp formulations (identical math; used by the
-                         512-device dry-run where Mosaic cannot lower)
+- ``xla``              — pure-jnp formulations (identical math; used
+                         where Mosaic cannot lower, e.g. simulated CPU
+                         meshes)
 
 Layering: this module is the *dispatch-tier* layer — it consumes raw
 arrays only (plan leaves arrive via the executor pipeline in

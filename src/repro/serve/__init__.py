@@ -1,9 +1,6 @@
-"""Serving: KV/SSM-cache engine with prefill + decode steps, plus the
-request-batching SpMM service front (bounded admission, deadlines,
-quarantine — see ``SpmmService.health()``)."""
-from . import engine, spmm_service
-from .engine import ServeConfig, ServeEngine
+"""Serving: the request-batching SpMM service front (bounded admission,
+deadlines, quarantine — see ``SpmmService.health()``)."""
+from . import spmm_service
 from .spmm_service import ADMISSION_POLICIES, ServiceStats, SpmmService
 
-__all__ = ["engine", "spmm_service", "ServeConfig", "ServeEngine",
-           "ADMISSION_POLICIES", "ServiceStats", "SpmmService"]
+__all__ = ["spmm_service", "ADMISSION_POLICIES", "ServiceStats", "SpmmService"]

@@ -34,7 +34,7 @@ from repro.dynamic import (  # noqa: E402
     DynamicPlan, GraphDelta, build_delta_fringe, update_values,
 )
 from repro.exec import dispatch_count  # noqa: E402
-from repro.launch.mesh import make_spmm_mesh  # noqa: E402
+from repro.distributed.sharding import make_spmm_mesh  # noqa: E402
 
 
 def _coo(seed, m, k, density):

@@ -24,7 +24,7 @@ import numpy as np  # noqa: E402
 
 from repro.core import spmm  # noqa: E402
 from repro.exec import execute_sddmm, execute_spspmm  # noqa: E402
-from repro.launch.mesh import make_spmm_mesh  # noqa: E402
+from repro.distributed.sharding import make_spmm_mesh  # noqa: E402
 
 
 def _coo(rng, m, k, nnz):

@@ -29,7 +29,7 @@ import numpy as np  # noqa: E402
 
 from repro.core import spmm  # noqa: E402
 from repro.data import graphs  # noqa: E402
-from repro.launch.mesh import make_spmm_mesh  # noqa: E402
+from repro.distributed.sharding import make_spmm_mesh  # noqa: E402
 
 ORACLE_PANEL = ["cora", "F1", "reddit"]
 

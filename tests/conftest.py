@@ -2,8 +2,8 @@ import os
 import subprocess
 import sys
 
-# Tests run single-device by default (the dry-run and the simulated-mesh
-# parity suite run their multi-device workloads in subprocesses; the CI
+# Tests run single-device by default (the simulated-mesh parity suite
+# runs its multi-device workloads in subprocesses; the CI
 # mesh leg exports XLA_FLAGS itself so the in-process mesh tests unskip).
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 

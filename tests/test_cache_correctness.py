@@ -10,7 +10,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import spmm
-from repro.launch.mesh import make_spmm_mesh
+from repro.distributed.sharding import make_spmm_mesh
 from conftest import make_sparse
 
 

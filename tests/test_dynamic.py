@@ -19,7 +19,7 @@ from repro.core.cost_model import (
 )
 from repro.data import graphs
 from repro.dynamic import DynamicPlan, GraphDelta, update_values
-from repro.launch.mesh import make_spmm_mesh
+from repro.distributed.sharding import make_spmm_mesh
 from _hyp import HAVE_HYPOTHESIS, given, settings, st
 
 BN = 128

@@ -174,7 +174,7 @@ def test_missing_entry_and_bad_names(tmp_path):
 
 
 def _sharded_dplan(rng, rows, cols, vals, shape, shard_axis="rows"):
-    from repro.launch.mesh import make_spmm_mesh
+    from repro.distributed.sharding import make_spmm_mesh
 
     splan = spmm.prepare_sharded(rows, cols, vals, shape, make_spmm_mesh(1),
                                  CFG, shard_axis=shard_axis)
@@ -211,7 +211,7 @@ def test_sharded_truncated_shard_raises_then_falls_back(rng, tmp_path):
     """Corruption handling mirrors the single-device entries: truncated
     data raises a clean RegistryError and load_or_prepare_sharded answers
     with a fresh prepare_sharded."""
-    from repro.launch.mesh import make_spmm_mesh
+    from repro.distributed.sharding import make_spmm_mesh
 
     a, rows, cols, vals = _graph(rng)
     reg = PlanRegistry(str(tmp_path))
@@ -256,7 +256,7 @@ def test_sharded_manifest_and_version_corruption(rng, tmp_path):
 def test_sharded_warm_start_matches_fingerprint(rng, tmp_path):
     """load_or_prepare_sharded restores mutated state when the caller's COO
     matches the stored fingerprint, and prepares fresh when it doesn't."""
-    from repro.launch.mesh import make_spmm_mesh
+    from repro.distributed.sharding import make_spmm_mesh
 
     a, rows, cols, vals = _graph(rng)
     reg = PlanRegistry(str(tmp_path))

@@ -19,7 +19,7 @@ from repro.exec import (
     EXECUTOR_CACHE, build_executor, dispatch_count, fused_trace_count,
     sharded_trace_count,
 )
-from repro.launch.mesh import make_spmm_mesh
+from repro.distributed.sharding import make_spmm_mesh
 from conftest import make_sparse
 
 BN = 128  # narrow n-blocks keep interpret-mode grids small

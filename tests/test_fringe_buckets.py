@@ -9,7 +9,7 @@ from repro.core import plan_ir, spmm
 from repro.dynamic.delta import update_values
 from repro.exec.api import execute, execute_sharded
 from repro.kernels import ops, ref
-from repro.launch.mesh import make_spmm_mesh
+from repro.distributed.sharding import make_spmm_mesh
 from repro.obs import REGISTRY
 
 

@@ -22,7 +22,7 @@ from repro.exec import (
     dispatch_count, execute_sddmm, execute_spspmm, fused_trace_count,
 )
 from repro.kernels import ops
-from repro.launch.mesh import make_spmm_mesh
+from repro.distributed.sharding import make_spmm_mesh
 from repro.obs import REGISTRY
 import repro.sparse as sp
 from conftest import make_sparse

@@ -18,7 +18,7 @@ import pytest
 from repro.core import spmm
 from repro.core.cost_model import default_cost_model, select_shard_axis
 from repro.core.coordinator import window_costs_from_coo
-from repro.launch.mesh import make_spmm_mesh
+from repro.distributed.sharding import make_spmm_mesh
 from conftest import make_sparse
 
 N_DEVICES = len(jax.devices())

@@ -6,7 +6,7 @@ from repro.core import spmm
 from repro.data import graphs
 from repro.dynamic import GraphDelta
 from repro.errors import AdmissionError
-from repro.launch.mesh import make_spmm_mesh
+from repro.distributed.sharding import make_spmm_mesh
 from repro.serve import SpmmService
 from conftest import make_sparse
 

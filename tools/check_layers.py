@@ -10,7 +10,8 @@ above itself:
 
     errors, obs             (shared taxonomy + telemetry: no repro deps)
     robust                  (fault harness: errors + obs only)
-    kernels, distributed    (leaf utilities)
+    kernels, distributed    (leaf utilities: Pallas kernels; the 1-D
+                             mesh and spec helpers of the sharded executor)
         -> core             (plan IR + plan builders)
         -> exec             (executor pipeline + health table)
         -> dynamic          (incremental plan maintenance)
@@ -68,8 +69,7 @@ FORBIDDEN = {
     "obs": ("repro",),
     "robust": ("repro",),
     "kernels": ("repro.core", "repro.exec", "repro.dynamic", "repro.serve",
-                "repro.distributed", "repro.launch", "repro.models",
-                "repro.train", "repro.sparse"),
+                "repro.distributed", "repro.sparse"),
     "distributed": ("repro.core", "repro.exec", "repro.dynamic",
                     "repro.serve", "repro.sparse"),
     "core": ("repro.exec", "repro.dynamic", "repro.serve", "repro.sparse"),
